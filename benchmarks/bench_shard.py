@@ -5,13 +5,14 @@ panel twice — unsharded on one device, and column-sharded over an
 ``n_devices``-wide mesh — and records panel throughput (columns/s) plus
 the sharded speedup into ``results/shard/``.
 
-If the current process doesn't see enough devices (the usual case on CPU:
-jax binds the platform device count at import), the benchmark RE-EXECUTES
-itself in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=<n>`` so the mesh path
-runs everywhere, CI included.  Fake host devices share one physical CPU,
-so the recorded "speedup" there measures dispatch overhead, not real
-scaling — the JSON carries ``forced_host_devices`` so readers can tell.
+The mesh runs over the devices this process already sees; the benchmark
+never starts a child process (one holding a chip would fight its parent
+for it).  On a host with fewer devices it fails and says how to get
+more: on CPU, set ``XLA_FLAGS=--xla_force_host_platform_device_count=<n>``
+and ``JAX_PLATFORMS=cpu`` before starting Python.  Fake host devices share
+one physical CPU, so a "speedup" there measures dispatch overhead, not
+real scaling — the JSON carries ``forced_host_devices`` so readers can
+tell.
 
     PYTHONPATH=src python -m benchmarks.bench_shard [n] [r] [n_devices]
 """
@@ -19,7 +20,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 
 import jax
@@ -30,32 +30,14 @@ from .common import emit, timeit
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "shard")
 
 
-def _respawn_with_devices(n: int, r: int, n_devices: int) -> dict:
-    """Re-exec this module in a subprocess that forces the device count."""
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    env["XLA_FLAGS"] = (flags + " " if flags else "") + \
-        f"--xla_force_host_platform_device_count={n_devices}"
-    root = os.path.join(os.path.dirname(__file__), "..")
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_shard",
-         str(n), str(r), str(n_devices)],
-        cwd=root, env=env, text=True, capture_output=True, timeout=3600)
-    sys.stdout.write(out.stdout)
-    if out.returncode != 0:
-        sys.stderr.write(out.stderr)
-        raise RuntimeError("bench_shard subprocess failed")
-    with open(os.path.join(RESULTS, "shard_panel.json")) as f:
-        return json.load(f)
-
-
 def run(n: int = 8192, r: int = 64, n_devices: int = 4, c_leaf: int = 128,
         k: int = 16, sigma2: float = 0.5, tol: float = 1e-4,
         max_iter: int = 200) -> dict:
     if jax.device_count() < n_devices:
-        return _respawn_with_devices(n, r, n_devices)
+        raise RuntimeError(
+            f"bench_shard needs {n_devices} devices but this process sees "
+            f"{jax.device_count()}; on CPU start it with JAX_PLATFORMS=cpu "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={n_devices}")
 
     import numpy as np
 

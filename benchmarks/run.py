@@ -25,6 +25,10 @@ import sys
 import time
 import traceback
 
+import jax
+
+from repro.runtime.compile_cache import enable_compile_cache
+
 from . import (bench_batching, bench_build, bench_chaos, bench_compare,
                bench_complexity, bench_convergence, bench_harith,
                bench_matmat, bench_memory, bench_roofline, bench_serve,
@@ -41,7 +45,8 @@ def _suites(args) -> list:
             ("matmat", lambda: bench_matmat.run(n=1024, rs=(1, 8))),
             ("solve", lambda: bench_solve.run(n=1024, domain=16.0,
                                               c_leaf=128)),
-            ("shard", lambda: bench_shard.run(n=512, r=8)),
+            ("shard", lambda: bench_shard.run(
+                n=512, r=8, n_devices=jax.device_count())),
             ("build", lambda: bench_build.run(smoke=True)),
             ("serve", lambda: bench_serve.run(smoke=True)),
             ("tenancy", lambda: bench_tenancy.run(smoke=True)),
@@ -60,7 +65,8 @@ def _suites(args) -> list:
         ("solve", lambda: bench_solve.run(n=4096, domain=16.0) if args.quick
          else bench_solve.run()),
         ("shard", lambda: bench_shard.run(n=2048 if args.quick else 8192,
-                                          r=16 if args.quick else 64)),
+                                          r=16 if args.quick else 64,
+                                          n_devices=jax.device_count())),
         ("build", lambda: bench_build.run(n=4096, reps=9) if args.quick
          else bench_build.run()),
         ("serve", lambda: bench_serve.run(smoke=True) if args.quick
@@ -172,6 +178,7 @@ def main() -> None:
                     help="run the hlint device-discipline scan before "
                          "benchmarking; abort on findings")
     args = ap.parse_args()
+    enable_compile_cache()
 
     lint_report = _lint_preflight() if args.lint else None
 

@@ -21,11 +21,13 @@ import jax.numpy as jnp
 
 from repro.core import build_hmatrix, halton, make_apply, sinusoid_targets
 from repro.solve import make_solver
+from repro.runtime.compile_cache import enable_compile_cache
 
 DOMAIN = 32.0  # domain side length (kernel length scale is 1)
 
 
 def main():
+    enable_compile_cache()
     n, sigma2 = 16384, 1e-2
     pts = halton(n, 2) * DOMAIN
     F = sinusoid_targets(pts, 8, DOMAIN)                      # (N, R)

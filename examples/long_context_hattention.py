@@ -16,6 +16,7 @@ import numpy as np
 from repro.core.hattention import causal_hmatrix_plan, h_attention
 from repro.configs.registry import get_smoke
 from repro.models.api import get_model
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 # module-level jit: a jax.jit(lambda ...) inside main() would recompile on
@@ -26,6 +27,7 @@ def _h_fn(q, k, v, c_leaf, rank):
 
 
 def main():
+    enable_compile_cache()
     s, c_leaf, rank = 4096, 256, 16
     plan = causal_hmatrix_plan(s, c_leaf)
     n_adm = sum(len(r) for r, _ in plan["levels"].values())

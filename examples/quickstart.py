@@ -10,9 +10,11 @@ import numpy as np
 
 from repro.core import (build_hmatrix, dense_matvec_oracle, halton,
                         make_matvec)
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     n, d = 8192, 2
     print(f"Halton point set: N={n}, d={d}, Gaussian kernel")
     pts = halton(n, d)

@@ -8,9 +8,10 @@ real 135M config with a small batch.
     PYTHONPATH=src python examples/train_lm.py [--full] [--steps 300]
 """
 import argparse
+import os
 import subprocess
 import sys
-import os
+import tempfile
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -27,7 +28,7 @@ def main():
            "--steps", str(args.steps),
            "--batch", "8" if not args.full else "2",
            "--seq-len", "128",
-           "--ckpt-dir", "/tmp/repro_train_lm",
+           "--ckpt-dir", os.path.join(tempfile.gettempdir(), "repro_train_lm"),
            "--ckpt-every", "100",
            "--log-every", "20"]
     if not args.full:

@@ -43,17 +43,25 @@ def aca_fixed_rank(row_pts: jnp.ndarray, col_pts: jnp.ndarray,
     """
     m, n = row_pts.shape[0], col_pts.shape[0]
     dtype = row_pts.dtype
-    U0 = jnp.zeros((m, k), dtype)
-    V0 = jnp.zeros((n, k), dtype)
-    row_mask0 = jnp.ones((m,), dtype)
-    col_mask0 = jnp.ones((n,), dtype)
-    j0 = jnp.asarray(0, jnp.int32)
+    # carries built *like* the points: inside a shard_map whose block
+    # lists are split over a mesh axis (hshard's row sharding) the points
+    # vary over that axis, and the loop carry must vary with them.  The
+    # factors are carried transposed, (k, m) and (k, n): with the block
+    # axis minor a batch of them is lane-dense on the TPU, where (m, k)
+    # pads k = 16 to 128 lanes and multiplies the loop's memory by 8.
+    like = row_pts + col_pts[:1]
+    Ut0 = jnp.zeros_like(like, shape=(k, m))
+    Vt0 = jnp.zeros_like(like, shape=(k, n))
+    row_mask0 = jnp.ones_like(like, shape=(m,))
+    col_mask0 = jnp.ones_like(like, shape=(n,))
+    j0 = jnp.zeros_like(like, jnp.int32, shape=())
 
     def body(r, carry):
-        U, V, row_mask, col_mask, j_r = carry
-        # residual column j_r:  A[:, j_r] - U @ V[j_r]
+        Ut, Vt, row_mask, col_mask, j_r = carry
+        # residual column j_r:  A[:, j_r] - U @ V[j_r]   (a k-term sum per
+        # entry, taken elementwise in float32 rather than as a matmul)
         a_col = kernel(row_pts, col_pts[j_r][None, :])[:, 0]
-        u_hat = a_col - U @ V[j_r]
+        u_hat = a_col - jnp.sum(Vt[:, j_r][:, None] * Ut, axis=0)
         i_r = _masked_argmax(u_hat, row_mask)
         alpha = u_hat[i_r]
         safe = jnp.abs(alpha) > jnp.asarray(1e-30, dtype)
@@ -61,18 +69,19 @@ def aca_fixed_rank(row_pts: jnp.ndarray, col_pts: jnp.ndarray,
         u_r = u_hat * inv
         # residual row i_r:  A[i_r, :] - V @ U[i_r]
         a_row = kernel(row_pts[i_r][None, :], col_pts)[0, :]
-        v_r = a_row - V @ U[i_r]
+        v_r = a_row - jnp.sum(Ut[:, i_r][:, None] * Vt, axis=0)
         v_r = jnp.where(safe, v_r, jnp.zeros_like(v_r))
         u_r = jnp.where(safe, u_r, jnp.zeros_like(u_r))
-        U = U.at[:, r].set(u_r)
-        V = V.at[:, r].set(v_r)
+        Ut = Ut.at[r].set(u_r)
+        Vt = Vt.at[r].set(v_r)
         row_mask = row_mask.at[i_r].set(0.0)
         col_mask = col_mask.at[j_r].set(0.0)
         j_next = _masked_argmax(v_r, col_mask).astype(jnp.int32)
-        return U, V, row_mask, col_mask, j_next
+        return Ut, Vt, row_mask, col_mask, j_next
 
-    U, V, _, _, _ = jax.lax.fori_loop(0, k, body, (U0, V0, row_mask0, col_mask0, j0))
-    return U, V
+    Ut, Vt, _, _, _ = jax.lax.fori_loop(
+        0, k, body, (Ut0, Vt0, row_mask0, col_mask0, j0))
+    return Ut.T, Vt.T
 
 
 @partial(jax.jit, static_argnames=("kernel", "k"))

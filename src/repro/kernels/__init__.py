@@ -1,12 +1,35 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-"""Shared kernel-package helpers."""
+"""Shared kernel-package helpers.
+
+Each package holds ``kernel.py`` (the Pallas kernel), ``ref.py`` (its jnp
+oracle) and ``ops.py`` (the dispatcher, which sends a shape whose VMEM
+working set exceeds the package's ``VMEM_BUDGET`` to the oracle).
+"""
 from __future__ import annotations
 
+import math
 import os
 
 import jax
+
+# The TPU kernel compiler's scoped VMEM limit is 16 MiB per program on
+# v5e; keep a quarter of it for the compiler's own scratch.
+VMEM_LIMIT = 12 * 1024 * 1024
+
+
+def tile_bytes(shape, itemsize: int = 4) -> int:
+    """Bytes of one VMEM buffer of ``shape``: the last two dimensions are
+    padded to the (8, 128) sublane x lane tile."""
+    dims = (1,) * (2 - len(shape)) + tuple(shape)
+    lead = math.prod(dims[:-2])
+    return (lead * (-(-dims[-2] // 8) * 8) * (-(-dims[-1] // 128) * 128)
+            * itemsize)
+
+
+def vmem_bytes(blocks, temps=(), itemsize: int = 4) -> int:
+    """VMEM of one kernel program: its pipelined ``blocks`` (inputs and
+    outputs, double-buffered) plus the in-body ``temps``."""
+    return (2 * sum(tile_bytes(b, itemsize) for b in blocks)
+            + sum(tile_bytes(t, itemsize) for t in temps))
 
 
 def force_ref() -> bool:

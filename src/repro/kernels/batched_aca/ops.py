@@ -10,22 +10,23 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import force_ref
+from repro.kernels import VMEM_LIMIT, force_ref, vmem_bytes
 
 from .kernel import batched_aca_t, batched_lowrank_matmat_t
 from .ref import (batched_aca_level_ref, batched_aca_ref,
                   batched_lowrank_matmat_ref)
 
-# Conservative VMEM budget for one program's working set (bytes).
-VMEM_BUDGET = 8 * 1024 * 1024
+VMEM_BUDGET = VMEM_LIMIT
 
 
-def _vmem_bytes(m: int, n: int, d: int, k: int, itemsize: int = 4) -> int:
-    return itemsize * (d * (m + n) + 2 * (m * k + n * k) + 4 * (m + n))
+def _vmem_bytes(m: int, n: int, d: int, k: int) -> int:
+    # lane-major points and factors; U^T/V^T carries plus pivot rows/masks
+    return vmem_bytes([(d, m), (d, n), (k, m), (k, n)],
+                      [(k, m), (k, n), (8, m), (8, n)])
 
 
-def _lowrank_vmem_bytes(m: int, n: int, k: int, r: int, itemsize: int = 4) -> int:
-    return itemsize * (m * k + n * k + n * r + k * r + m * r)
+def _lowrank_vmem_bytes(m: int, n: int, k: int, r: int) -> int:
+    return vmem_bytes([(m, k), (n, k), (n, r), (m, r)], [(k, r)])
 
 
 def batched_aca_pallas(rows: jnp.ndarray, cols: jnp.ndarray,

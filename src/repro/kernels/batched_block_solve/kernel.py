@@ -7,17 +7,16 @@ triangular solves applied every CG iteration.  Both stages run entirely in
 VMEM, one program per block:
 
   * ``batched_block_cholesky_t`` — right-looking Cholesky as ``c`` pivoted
-    rank-1 updates (``fori_loop``; column/row extracted by dynamic slice,
-    the trailing submatrix update is a VPU outer-product subtraction — the
-    residual matrix stays symmetric, so the pivot row is read directly
-    instead of transposing the pivot column);
+    rank-1 updates (``fori_loop``; pivot column and row extracted by masked
+    reductions, the trailing submatrix update is a VPU outer-product
+    subtraction);
   * ``batched_block_cholesky_solve_t`` — forward + back substitution on a
-    ``(c, R)`` panel (``L L^T Y = X``), ``2c`` axpy steps of O(c R) each;
-    ``L^T`` is materialised once per program so both sweeps read columns.
+    transposed ``(R, c)`` panel (``L L^T Y = X``), ``2c`` steps of O(c R)
+    each that read one row of ``L`` apiece.
 
 VMEM working set per program (c = C_leaf, f32):
     factorize: A + L                 2 * c * c * 4 B
-    solve:     L + L^T + X, Y panels (2 c^2 + 2 c R) * 4 B
+    solve:     L + X, Y panels       (c^2 + 3 c R) * 4 B
   c=512, R=64: ~2.3 MB << 16 MB VMEM.  ``ops.py`` falls back to the jnp
   oracle for blocks over the VMEM budget.
 """
@@ -44,10 +43,15 @@ def _chol_kernel(a_ref, l_ref):
 
     def body(j, carry):
         l_mat, a_r = carry
-        d2 = lax.dynamic_slice(a_r, (j, j), (1, 1))            # pivot A_r[j,j]
+        # row / column / pivot j of the residual by masked reductions (the
+        # TPU kernel compiler lowers no dynamic slice of a value)
+        row = jnp.sum(jnp.where(idx_col == j, a_r, 0.0), axis=0,
+                      keepdims=True)                           # A_r[j, :]
+        col = jnp.sum(jnp.where(idx_row == j, a_r, 0.0), axis=1,
+                      keepdims=True)                           # A_r[:, j]
+        d2 = jnp.sum(jnp.where(idx_row == j, row, 0.0), axis=1,
+                     keepdims=True)                            # A_r[j, j]
         dinv = lax.rsqrt(jnp.maximum(d2, jnp.asarray(_TINY, dtype)))
-        col = lax.dynamic_slice(a_r, (0, j), (c, 1))           # A_r[:, j]
-        row = lax.dynamic_slice(a_r, (j, 0), (1, c))           # A_r[j, :]
         l_col = jnp.where(idx_col >= j, col * dinv, 0.0)       # (c, 1)
         l_row = jnp.where(idx_row >= j, row * dinv, 0.0)       # (1, c)
         e_row = (idx_row == j).astype(dtype)
@@ -68,6 +72,7 @@ def batched_block_cholesky_t(a: jnp.ndarray,
     b, c, _ = a.shape
     return pl.pallas_call(
         _chol_kernel,
+        name="batched_block_cholesky",
         grid=(b,),
         in_specs=[pl.BlockSpec((1, c, c), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((1, c, c), lambda i: (i, 0, 0)),
@@ -76,54 +81,64 @@ def batched_block_cholesky_t(a: jnp.ndarray,
     )(a)
 
 
-def _chol_solve_kernel(l_ref, x_ref, y_ref):
-    l_mat = l_ref[0]                               # (c, c) lower
-    x = x_ref[0]                                   # (c, R)
-    c, r = x.shape
-    dtype = x.dtype
-    idx_col = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
-    lt = jnp.swapaxes(l_mat, 0, 1)                 # (c, c) upper, once
+def _chol_solve_kernel(l_ref, xt_ref, zt_ref):
+    xt = xt_ref[0]                                 # (R, c): panel, transposed
+    c = xt.shape[1]
+    lanes = lax.broadcasted_iota(jnp.int32, (1, c), 1)
 
-    def fwd(j, carry):
-        y, xr = carry
-        l_col = lax.dynamic_slice(l_mat, (0, j), (c, 1))       # zeros above j
-        d = lax.dynamic_slice(l_mat, (j, j), (1, 1))
-        yj = lax.dynamic_slice(xr, (j, 0), (1, r)) / d         # (1, R)
-        y = y + (idx_col == j).astype(dtype) * yj
-        xr = xr - l_col * yj
-        return y, xr
+    def l_row(j):
+        return l_ref[0, pl.ds(j, 1), :]            # (1, c): row j of L
+
+    def fwd(j, yt):
+        # row form  y_j = (x_j - L[j, :j] y[:j]) / L[j, j]
+        row = l_row(j)
+        hot = lanes == j
+        d = jnp.sum(jnp.where(hot, row, 0.0), axis=1, keepdims=True)
+        xj = jnp.sum(jnp.where(hot, xt, 0.0), axis=1, keepdims=True)
+        s = jnp.sum(row * yt, axis=1, keepdims=True)   # y is 0 from lane j on
+        return yt + jnp.where(hot, (xj - s) / d, 0.0)
 
     def bwd(t, carry):
-        z, yr = carry
+        # column form of L^T z = y:  z_i = y_i / L[i, i], then
+        # y[:i] -= L[i, :i]^T z_i  (column i of L^T is row i of L)
+        zt, yt = carry
         i = c - 1 - t
-        lt_col = lax.dynamic_slice(lt, (0, i), (c, 1))         # zeros below i
-        d = lax.dynamic_slice(lt, (i, i), (1, 1))
-        zi = lax.dynamic_slice(yr, (i, 0), (1, r)) / d         # (1, R)
-        z = z + (idx_col == i).astype(dtype) * zi
-        yr = yr - lt_col * zi
-        return z, yr
+        row = l_row(i)
+        hot = lanes == i
+        d = jnp.sum(jnp.where(hot, row, 0.0), axis=1, keepdims=True)
+        zi = jnp.sum(jnp.where(hot, yt, 0.0), axis=1, keepdims=True) / d
+        zt = zt + jnp.where(hot, zi, 0.0)
+        yt = yt - zi * jnp.where(lanes < i, row, 0.0)
+        return zt, yt
 
-    y, _ = lax.fori_loop(0, c, fwd, (jnp.zeros_like(x), x))    # L Y1 = X
-    z, _ = lax.fori_loop(0, c, bwd, (jnp.zeros_like(x), y))    # L^T Y = Y1
-    y_ref[0] = z
+    yt = lax.fori_loop(0, c, fwd, jnp.zeros_like(xt))          # L Y1 = X
+    zt, _ = lax.fori_loop(0, c, bwd, (jnp.zeros_like(xt), yt))  # L^T Y = Y1
+    zt_ref[0] = zt
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def batched_block_cholesky_solve_t(l: jnp.ndarray, x: jnp.ndarray,
                                    interpret: bool | None = None) -> jnp.ndarray:
-    """Y[b] = (L[b] L[b]^T)^{-1} X[b].  l: (B, c, c), x: (B, c, R)."""
+    """Y[b] = (L[b] L[b]^T)^{-1} X[b].  l: (B, c, c), x: (B, c, R).
+
+    The kernel works on the transposed panel (B, R, c) so that every
+    substitution step reads one ROW of ``L`` (a dynamic sublane load) and
+    touches O(c R) values; the transposes are fused by XLA.
+    """
     if interpret is None:
         interpret = default_interpret()
     b, c, _ = l.shape
     r = x.shape[2]
-    return pl.pallas_call(
+    zt = pl.pallas_call(
         _chol_solve_kernel,
+        name="batched_block_cholesky_solve",
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, c, c), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, c, r), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, r, c), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, c, r), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, c, r), x.dtype),
+        out_specs=pl.BlockSpec((1, r, c), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, r, c), x.dtype),
         interpret=interpret,
-    )(l, x)
+    )(l, jnp.swapaxes(x, 1, 2))
+    return jnp.swapaxes(zt, 1, 2)

@@ -9,21 +9,20 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import force_ref
+from repro.kernels import VMEM_LIMIT, force_ref, vmem_bytes
 
 from .kernel import batched_block_cholesky_solve_t, batched_block_cholesky_t
 from .ref import batched_block_cholesky_ref, batched_block_cholesky_solve_ref
 
-# Conservative VMEM budget for one program's working set (bytes).
-VMEM_BUDGET = 8 * 1024 * 1024
+VMEM_BUDGET = VMEM_LIMIT
 
 
-def _chol_vmem_bytes(c: int, itemsize: int = 4) -> int:
-    return itemsize * 2 * c * c
+def _chol_vmem_bytes(c: int) -> int:
+    return vmem_bytes([(c, c), (c, c)], [(c, c)] * 3)
 
 
-def _solve_vmem_bytes(c: int, r: int, itemsize: int = 4) -> int:
-    return itemsize * (2 * c * c + 3 * c * r)
+def _solve_vmem_bytes(c: int, r: int) -> int:
+    return vmem_bytes([(c, c), (r, c), (r, c)], [(r, c)] * 3)
 
 
 def batched_block_cholesky(a: jnp.ndarray) -> jnp.ndarray:

@@ -10,18 +10,17 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import force_ref
+from repro.kernels import VMEM_LIMIT, force_ref, vmem_bytes
 
-from .kernel import batched_kernel_matmat_t, batched_kernel_matvec_t
+from .kernel import batched_kernel_matmat_t
 from .ref import batched_kernel_matmat_ref, batched_kernel_matvec_ref
 
-# Conservative VMEM budget for one program's working set (bytes).
-VMEM_BUDGET = 8 * 1024 * 1024
+VMEM_BUDGET = VMEM_LIMIT
 
 
-def _vmem_bytes(c: int, d: int, r: int = 1, itemsize: int = 4) -> int:
-    # generated (C, C) block + two (d, C) point tiles + (C, R) operand/out
-    return itemsize * (c * c + 2 * d * c + 2 * c * r)
+def _vmem_bytes(c: int, d: int, r: int = 1) -> int:
+    # two (d, C) point tiles + (C, R) operand/out; generated (C, C) block
+    return vmem_bytes([(d, c), (d, c), (c, r), (c, r)], [(c, c), (c, c)])
 
 
 def batched_kernel_matvec(rows: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray,
@@ -40,16 +39,15 @@ def batched_kernel_matvec(rows: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray,
     Returns
     -------
     y : jnp.ndarray, shape (B, C)
-        Per-block products; the kernel block is generated in VMEM and never
-        materialised in HBM (paper §5.4.2).  Leaf sizes whose working set
-        exceeds ``VMEM_BUDGET`` fall back to the jnp reference path.
+        Per-block products through the matmat kernel as one-column panels;
+        the kernel block is generated in VMEM and never materialised in
+        HBM (paper §5.4.2).  Leaf sizes whose working set exceeds
+        ``VMEM_BUDGET`` fall back to the jnp reference path.
     """
     _, c, d = rows.shape
     if force_ref() or _vmem_bytes(c, d) > VMEM_BUDGET:
         return batched_kernel_matvec_ref(rows, cols, x, kernel_name)
-    rows_t = jnp.swapaxes(rows, -1, -2)
-    cols_t = jnp.swapaxes(cols, -1, -2)
-    return batched_kernel_matvec_t(rows_t, cols_t, x, kernel_name)
+    return batched_kernel_matmat(rows, cols, x[..., None], kernel_name)[..., 0]
 
 
 def batched_kernel_matmat(rows: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray,

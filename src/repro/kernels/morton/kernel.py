@@ -26,8 +26,16 @@ TILE = 1024
 def _kernel(coords_t_ref, hi_ref, lo_ref, *, d: int, nb: int):
     coords_t = coords_t_ref[...]                # (d, TILE)
     scale = jnp.float32(2.0**nb - 1.0)
-    fx = jnp.minimum((jnp.clip(coords_t, 0.0, 1.0) * scale).astype(jnp.uint32),
-                     jnp.uint32(2**nb - 1))
+    q = jnp.clip(coords_t, 0.0, 1.0) * scale    # in [0, 2^nb], nb <= 32
+    # the TPU has no float32 -> uint32 convert: truncate the two 16-bit
+    # halves separately (both exact in float32, both fit int32), which
+    # reproduces the reference's q.astype(uint32) bit for bit
+    q_hi = jnp.floor(q * (1.0 / 65536.0))
+    q_lo = q - q_hi * 65536.0
+    fx = ((q_hi.astype(jnp.int32).astype(jnp.uint32) << jnp.uint32(16))
+          + q_lo.astype(jnp.int32).astype(jnp.uint32))
+    # float32(2^nb - 1) may round up to 2^nb: clamp like the reference
+    fx = jnp.where(q >= 2.0**nb, jnp.uint32(2**nb - 1), fx)
     lo = jnp.zeros((coords_t.shape[1],), jnp.uint32)
     hi = jnp.zeros((coords_t.shape[1],), jnp.uint32)
     one = jnp.uint32(1)
@@ -53,6 +61,7 @@ def morton_encode_t(coords_t: jnp.ndarray, interpret: bool | None = None):
     grid = (n // TILE,)
     return pl.pallas_call(
         functools.partial(_kernel, d=d, nb=nb),
+        name="morton_encode",
         grid=grid,
         in_specs=[pl.BlockSpec((d, TILE), lambda i: (0, i))],
         out_specs=[pl.BlockSpec((TILE,), lambda i: (i,)),

@@ -3,17 +3,18 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels import VMEM_LIMIT, vmem_bytes
+
 from .kernel import TILE, morton_encode_t
 from .ref import morton_encode_ref
 
-# Conservative VMEM budget for one program's working set (bytes).
-VMEM_BUDGET = 8 * 1024 * 1024
+VMEM_BUDGET = VMEM_LIMIT
 
 
-def _vmem_bytes(d: int, itemsize: int = 4) -> int:
+def _vmem_bytes(d: int) -> int:
     # one (d, TILE) coordinate tile plus the hi/lo uint32 output lanes and
     # the per-dimension interleave scratch
-    return itemsize * TILE * (2 * d + 2)
+    return vmem_bytes([(d, TILE), (TILE,), (TILE,)], [(d, TILE)] * 2)
 
 
 def morton_encode_pallas(coords: jnp.ndarray):

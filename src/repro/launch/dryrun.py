@@ -30,7 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis.flops import attention_extra_flops, model_flops
 from repro.analysis.hlo import analyze_hlo
-from repro.analysis.roofline import roofline_terms
+from repro.analysis.roofline import V5E, chip_peaks, roofline_terms
 from repro.configs.base import SHAPES, shape_applicable
 from repro.configs.registry import get_arch, get_shape, iter_cells, list_archs
 from repro.launch.mesh import make_production_mesh
@@ -195,7 +195,7 @@ def analyze_cell(arch_name: str, shape_name: str, multi_pod: bool,
         flops_per_chip=stats.dot_flops,
         hbm_bytes_per_chip=stats.traffic_bytes,
         collective_bytes_per_chip=stats.collective_bytes,
-        model_flops_per_chip=mf / chips)
+        model_flops_per_chip=mf / chips, device_kind=V5E)
     record["model_flops_global"] = mf
     record["params"] = count_params_analytic(cfg)
     record["roofline"] = terms.as_dict()
@@ -216,9 +216,9 @@ def analyze_cell(arch_name: str, shape_name: str, multi_pod: bool,
         ideal_bytes = param_bytes / tp
     else:
         ideal_bytes = param_bytes / tp + cache_bytes / chips
-    from repro.analysis.roofline import HBM_BW, PEAK_FLOPS
-    ideal_mem_s = ideal_bytes / HBM_BW
-    ideal_s = max(ideal_mem_s, mf / chips / PEAK_FLOPS)
+    peaks = chip_peaks(V5E)
+    ideal_mem_s = ideal_bytes / peaks.hbm_bw
+    ideal_s = max(ideal_mem_s, mf / chips / peaks.flops)
     record["ideal"] = {"bytes_per_chip": ideal_bytes,
                        "memory_s": ideal_mem_s,
                        "bound_s": ideal_s,

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.configs.registry import get_arch, get_smoke, list_archs
 from repro.models.api import get_model
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve.step import greedy_sample, make_decode_step, make_prefill_step
 
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--decode-steps", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (get_smoke(args.arch) if args.smoke else get_arch(args.arch))
     if args.smoke:

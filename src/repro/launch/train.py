@@ -20,6 +20,7 @@ import numpy as np
 from repro.configs.registry import get_arch, get_smoke, list_archs
 from repro.data.pipeline import DataConfig, make_batch
 from repro.runtime.checkpoint import CheckpointManager
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault_tolerance import PreemptionHandler
 from repro.serve.faults import StragglerMonitor, run_with_restarts
 from repro.train.optimizer import AdamWConfig
@@ -97,6 +98,7 @@ def main():
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--max-restarts", type=int, default=3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (get_smoke(args.arch) if args.smoke else get_arch(args.arch))
     if args.smoke:

@@ -47,8 +47,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.clustering import permute_from_tree, permute_to_tree
 from repro.core.hmatrix import HMatrix, apply_in_tree_order, tree_kernel_name
-from repro.parallel.mesh_ctx import (mesh_axes, mesh_axes_size,
-                                     shard_map_compat)
+from repro.parallel.mesh_ctx import mesh_axes, mesh_axes_size
 from repro.solve.cg import build_preconditioner, pcg_tree_ordered
 
 
@@ -172,7 +171,7 @@ def _make_colsharded_apply(hm: HMatrix, mesh: Mesh, axis, use_pallas):
                                     points, factors or None, x_pad)
         return permute_from_tree(tree, z_pad)
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         _body, mesh=mesh,
         in_specs=(P(), _replicated_specs(factors), P(None, axes)),
         out_specs=P(None, axes))
@@ -298,7 +297,7 @@ def _make_rowsharded_apply(hm: HMatrix, mesh: Mesh, axis, use_pallas):
         return lax.psum(z, axes)
 
     blk_specs = {lv: P(axes) for lv in levels}
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         _body, mesh=mesh,
         in_specs=(P(), blk_specs, blk_specs,
                   {lv: (P(axes), P(axes)) for lv in aca_uv},
@@ -387,7 +386,7 @@ def make_sharded_solver(hm: HMatrix, sigma2: float, mesh: Mesh, axis=None,
             b_pad, reduce_any)
         return permute_from_tree(tree, x), it, iters_col, jnp.sqrt(rr)
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         _body, mesh=mesh,
         in_specs=(P(), _replicated_specs(factors),
                   _replicated_specs(chol_tuple), P(None, axes)),

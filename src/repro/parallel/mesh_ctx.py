@@ -105,25 +105,6 @@ def named_sharding(shape, spec: P) -> NamedSharding | None:
     return NamedSharding(mesh, resolve_spec(shape, spec))
 
 
-# ---------------------------------------------------------------------------
-# shard_map version compat
-# ---------------------------------------------------------------------------
-# jax >= 0.5 exposes ``jax.shard_map``; 0.4.x only has
-# ``jax.experimental.shard_map.shard_map`` (whose replication checker is
-# stricter than the collectives we use, hence ``check_rep=False``).  Shared
-# by ``parallel.pipeline`` and ``parallel.hshard``.
-
-
-def shard_map_compat(f, *, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` on new jax, ``jax.experimental.shard_map`` on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def mesh_axes(mesh: Mesh, axis=None) -> tuple:
     """Normalise an axis selection to a tuple of mesh axis names.
 

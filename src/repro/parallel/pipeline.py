@@ -18,14 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.parallel.mesh_ctx import shard_map_compat as _shard_map
-
-# --- version compat -------------------------------------------------------
-# jax >= 0.5 exposes ``lax.pvary``; 0.4.x has no pvary (shard_map_compat
-# disables its replication checker instead, which pvary exists to satisfy).
-
-_pvary = getattr(lax, "pvary", None) or (lambda x, axes: x)
-
 
 def pipeline_apply(stage_params, x_microbatches, *, axis: str, n_stages: int,
                    stage_fn):
@@ -50,9 +42,9 @@ def pipeline_apply(stage_params, x_microbatches, *, axis: str, n_stages: int,
         params = jax.tree.map(lambda p: p[0], params_local)
         total = n_micro + n_stages - 1
         # mark the carries as device-varying along the pipeline axis
-        buf = _pvary(jnp.zeros_like(xs_local[0]), (axis,))
-        outs = _pvary(jnp.zeros((n_micro,) + xs_local.shape[1:],
-                                xs_local.dtype), (axis,))
+        buf = lax.pcast(jnp.zeros_like(xs_local[0]), (axis,), to="varying")
+        outs = lax.pcast(jnp.zeros((n_micro,) + xs_local.shape[1:],
+                                   xs_local.dtype), (axis,), to="varying")
 
         def tick(carry, t):
             buf, outs = carry
@@ -79,7 +71,7 @@ def pipeline_apply(stage_params, x_microbatches, *, axis: str, n_stages: int,
     mesh = jax.sharding.Mesh(
         *_current_mesh_parts(axis))
     from jax.sharding import PartitionSpec as P
-    return _shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

@@ -244,8 +244,15 @@ def pcg_tree_ordered(tree, plan, kernel, k: int, use_pallas: bool,
         return jnp.where(pad_rows, v, 0.0)
 
     def apply_op(v):
-        z = apply_in_tree_order(tree, plan, kernel, k, use_pallas,
-                                points, factors, v)
+        # The operator's matmuls run at HIGHEST precision.  At the TPU's
+        # default (one bfloat16 pass) the apply's relative error is ~3e-3
+        # of ||A||, which exceeds a small shift sigma2: on a v5e, N = 2^18
+        # Halton points on a side-128 square with sigma2 = 1e-2 stopped at
+        # 300 iterations with a relative residual of 1.1e4, where float32
+        # takes 168 iterations (CPU, N = 2^16, side 64).
+        with jax.default_matmul_precision("highest"):
+            z = apply_in_tree_order(tree, plan, kernel, k, use_pallas,
+                                    points, factors, v)
         return _mask(z + sigma2 * v)
 
     def prec(r):

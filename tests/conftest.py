@@ -1,69 +1,9 @@
 # NOTE: deliberately NO XLA_FLAGS / device-count forcing here — smoke tests
-# and benchmarks must see the single real CPU device (assignment
-# requirement).  Multi-device tests spawn subprocesses that set
+# and benchmarks must see the single real CPU device.  Multi-device tests
+# spawn subprocesses that set JAX_PLATFORMS=cpu and
 # --xla_force_host_platform_device_count themselves.
-import sys
-import types
-
 import numpy as np
 import pytest
-
-# ---------------------------------------------------------------------------
-# hypothesis fallback: offline environments without the package must still
-# COLLECT (and meaningfully run) the property tests.  When hypothesis is
-# missing we install a minimal stub that replays each @given test over a
-# small deterministic sample drawn from its strategies (bounds, midpoints,
-# round-robin over sampled_from choices) instead of random search.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    class _Strategy:
-        def __init__(self, examples):
-            self.examples = list(examples)
-
-    def _integers(lo, hi):
-        span = hi - lo
-        return _Strategy(dict.fromkeys(
-            [lo, hi, lo + span // 2, lo + span // 3, lo + (2 * span) // 3]))
-
-    def _sampled_from(choices):
-        return _Strategy(choices)
-
-    def _floats(lo, hi, **_kw):
-        return _Strategy([lo, hi, 0.5 * (lo + hi)])
-
-    def _given(*strategies):
-        def deco(fn):
-            # plain no-arg wrapper (no functools.wraps: its __wrapped__
-            # attribute would make pytest treat the original parameters
-            # as fixtures)
-            def wrapper():
-                n = max(len(s.examples) for s in strategies)
-                for i in range(n):
-                    vals = [s.examples[i % len(s.examples)]
-                            for s in strategies]
-                    fn(*vals)
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.hypothesis_stub = True
-            return wrapper
-        return deco
-
-    def _settings(**_kw):
-        return lambda fn: fn
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.sampled_from = _sampled_from
-    _st.floats = _floats
-    _hyp.strategies = _st
-    _hyp.HealthCheck = types.SimpleNamespace(all=lambda: [])
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
 
 
 @pytest.fixture(scope="session")

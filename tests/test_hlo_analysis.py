@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.analysis.hlo import analyze_hlo, parse_module
 from repro.analysis.roofline import roofline_terms
@@ -67,8 +68,15 @@ def test_traffic_nonzero_for_dot():
 
 def test_roofline_terms_dominance():
     t = roofline_terms(flops_per_chip=197e12, hbm_bytes_per_chip=1.0,
-                       collective_bytes_per_chip=1.0, model_flops_per_chip=197e12)
+                       collective_bytes_per_chip=1.0, model_flops_per_chip=197e12,
+                       device_kind="TPU v5 lite")
     assert t.dominant == "compute" and abs(t.compute_s - 1.0) < 1e-9
     assert abs(t.roofline_fraction - 1.0) < 1e-6
-    t2 = roofline_terms(1.0, 819e9, 1.0)
+    t2 = roofline_terms(1.0, 819e9, 1.0, device_kind="TPU v5 lite")
     assert t2.dominant == "memory" and abs(t2.memory_s - 1.0) < 1e-9
+
+
+def test_roofline_unknown_device_raises():
+    """A device without a peak table is an error, never v5e's peaks."""
+    with pytest.raises(ValueError, match="cpu"):
+        roofline_terms(1.0, 1.0, 1.0, device_kind="cpu")

@@ -202,6 +202,7 @@ def test_shard_suite_under_forced_devices():
     env["XLA_FLAGS"] = (flags + " " if flags else "") + \
         f"--xla_force_host_platform_device_count={N_DEV}"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"    # forced devices are host devices
     out = subprocess.run(
         [sys.executable, "-m", "pytest", "-q", "-m", "not slow", __file__],
         env=env, capture_output=True, text=True, timeout=1200)

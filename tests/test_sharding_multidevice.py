@@ -74,6 +74,7 @@ SCRIPT = textwrap.dedent("""
 def test_sharding_rules_and_debug_mesh_train():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"    # forced devices are host devices
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=560)
     assert "MULTIDEVICE_OK" in out.stdout, out.stdout + "\n" + out.stderr

@@ -72,10 +72,16 @@ def test_solver_single_vector_shape(rng):
 def test_active_mask_cg_matches_host_loop(rng):
     """The fused while_loop CG (no preconditioner) agrees with the host-loop
     CG at loose tolerance: both reach ||r|| < tol, so the solutions agree to
-    O(kappa * tol)."""
+    O(kappa * tol).
+
+    The stopping tolerance sits where the slowest column's residual falls
+    through it in one step (5.7e-5 -> 2.3e-6 between trips 23 and 24).  At
+    1e-6 that residual hovers at the threshold for three trips (1.02e-6,
+    1.09e-6, 5.3e-7): which trip stops there is decided by float32
+    rounding, which differs between the two loops' summation orders."""
     n = 700
     pts, hm, F = _system(n, "gaussian", rng, 8)
-    tol = 1e-6
+    tol = 1e-5
     solver = make_solver(hm, SIGMA2, tol=tol, max_iter=600, precondition=False)
     C, info = solver(F)
     ap = make_apply(hm)
