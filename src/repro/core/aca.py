@@ -48,7 +48,10 @@ def aca_fixed_rank(row_pts: jnp.ndarray, col_pts: jnp.ndarray,
     # vary over that axis, and the loop carry must vary with them.  The
     # factors are carried transposed, (k, m) and (k, n): with the block
     # axis minor a batch of them is lane-dense on the TPU, where (m, k)
-    # pads k = 16 to 128 lanes and multiplies the loop's memory by 8.
+    # pads k = 16 to 128 lanes.  On a v5e building N = 2^18 (c_leaf 256,
+    # k 16) the (m, k) carry took 4.30 s for the factor stage and 14.8 GB
+    # of temporaries for its largest level; this one takes 1.34 s and
+    # 6.7 GB, with the same apply error.
     like = row_pts + col_pts[:1]
     Ut0 = jnp.zeros_like(like, shape=(k, m))
     Vt0 = jnp.zeros_like(like, shape=(k, n))

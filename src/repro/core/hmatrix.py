@@ -282,9 +282,6 @@ def make_apply(hm: HMatrix, use_pallas: bool = False, mesh=None,
         all per-block work is batched over the R columns, so the ACA
         regeneration (NP mode) / factor streaming (P mode) cost is paid
         once for the whole panel instead of once per column.
-        ``apply.lower(x)`` returns that program lowered for ``x`` (its
-        text shows which kernels it runs; ``.compile()`` gives its
-        memory analysis).  Single-device executor only.
 
     Notes
     -----
@@ -321,12 +318,6 @@ def make_apply(hm: HMatrix, use_pallas: bool = False, mesh=None,
             return jnp.zeros_like(x)
         return _apply(tree.points, hm.factors, x)
 
-    def lower(x):
-        """The ``jax.stages.Lowered`` program that ``apply(x)`` runs."""
-        return _apply.lower(tree.points, hm.factors,
-                            x[:, None] if x.ndim == 1 else x)
-
-    apply.lower = lower
     return apply
 
 
