@@ -26,8 +26,15 @@ result is an :class:`~repro.core.hmatrix.HMatrix` whose plan, points,
 permutation and factors are BIT-IDENTICAL to the host oracle's (pinned
 by ``tests/test_build_device.py``): the structural program performs the
 same exact-arithmetic ops (gathers, min/max reductions, quantisation)
-and the factor stage reuses the very same ``batched_aca`` executable the
-host driver calls.
+and the factor stage runs the ``batched_aca`` operations of
+``compute_factors``.
+
+The build names its work for the profiler: device scopes
+``hmatrix.build.plan/{morton_sort,bbox,blocktree}`` and
+``hmatrix.build.aca.L{level}/{gather,aca}`` in the operations' metadata,
+and host spans ``hmatrix.build.plan`` (with ``.fetch`` inside) and
+``hmatrix.build.factors`` (with ``.dispatch.L{level}``, ``.wait`` and
+``.store`` inside) at the points the :class:`BuildReport` timers take.
 
 Chaos containment extends to construction: every stage launch is wrapped
 in the serving stack's :class:`~repro.serve.faults.FaultInjector` when a
@@ -62,6 +69,11 @@ from .geometry import get_kernel, KERNELS
 from .hmatrix import HMatrix
 from .morton import morton_encode
 
+# Names of the build's device scopes (op metadata) and host spans
+# (profiler annotations); docs/ARCHITECTURE.md lists what each covers.
+PLAN_SCOPE = "hmatrix.build.plan"
+ACA_SCOPE = "hmatrix.build.aca"
+SPAN = "hmatrix.build"
 
 # ---------------------------------------------------------------------------
 # The fused structural program (Algs. 6 + 7 + 1/4 in one launch)
@@ -85,6 +97,16 @@ def _plan_program(coords, *, n_pad: int, n_levels: int, eta: float):
     matches ``block_tree.build_block_tree`` exactly, which is what makes
     the emitted plan comparable array-for-array with the host oracle.
     """
+    with jax.named_scope(f"{PLAN_SCOPE}/morton_sort"):
+        spts, perm = _morton_sort(coords, n_pad)
+    with jax.named_scope(f"{PLAN_SCOPE}/bbox"):
+        mins, maxs = _boxes(spts, n_levels)
+    with jax.named_scope(f"{PLAN_SCOPE}/blocktree"):
+        meta = _block_tree(mins, maxs, n_levels, eta)
+    return spts, perm, tuple(mins), tuple(maxs), meta
+
+
+def _morton_sort(coords, n_pad: int):
     n, d = coords.shape
     # Alg. 6: quantise on the normalised unit box (same guard as
     # clustering.build_cluster_tree), encode, stable 2-key sort.
@@ -120,7 +142,11 @@ def _plan_program(coords, *, n_pad: int, n_levels: int, eta: float):
     if n_pad > n:
         spts = jnp.concatenate(
             [spts, jnp.broadcast_to(spts[-1], (n_pad - n, d))], axis=0)
+    return spts, perm
 
+
+def _boxes(spts, n_levels: int):
+    n_pad, d = spts.shape
     # Alg. 7: leaf boxes by reshape-reduce, parents by pairwise combine
     # (min/max reductions are order-exact, so these match the host's
     # eager _level_bounding_boxes bitwise).
@@ -135,7 +161,10 @@ def _plan_program(coords, *, n_pad: int, n_levels: int, eta: float):
         maxs.append(cur_max)
     mins.reverse()
     maxs.reverse()
+    return mins, maxs
 
+
+def _block_tree(mins, maxs, n_levels: int, eta: float):
     # Algs. 1/4: level-wise frontier advancement with static capacities.
     fr = jnp.zeros((1,), jnp.int32)
     fc = jnp.zeros((1,), jnp.int32)
@@ -172,9 +201,8 @@ def _plan_program(coords, *, n_pad: int, n_levels: int, eta: float):
         fc = (2 * c[:, None] + quad[None, :] % 2).reshape(-1)
         n_valid = 4 * split_sel.sum(dtype=jnp.int32)
 
-    meta = jnp.concatenate(
+    return jnp.concatenate(
         [jnp.stack(counts)] + [b.astype(jnp.int32) for b in blocks])
-    return spts, perm, tuple(mins), tuple(maxs), meta
 
 
 def _assemble_plan(meta: np.ndarray, c_leaf: int, n_pad: int,
@@ -265,13 +293,14 @@ def compute_factors_device(tree: ClusterTree, plan: HMatrixPlan,
                            _counters: dict | None = None) -> dict:
     """Device-side twin of ``hmatrix.compute_factors`` (paper §5.4.1).
 
-    One ``kernels/batched_aca`` construction launch per level group: the
-    cluster-point gather happens device-side from the tree-ordered point
-    array, so the host never touches coordinates.  The default
-    (``use_pallas=False``) routes through ``batched_aca_level_ref``,
-    whose gather + ``batched_aca`` call hits the SAME jitted executable
-    as the host driver — which is what makes the factors bit-identical
-    to ``compute_factors`` (pinned in tests).
+    One construction launch per level group: the cluster-point gather
+    happens device-side from the tree-ordered point array, so the host
+    never touches coordinates.  The default (``use_pallas=False``) runs
+    the gather and then the ``batched_aca`` program inlined under the
+    level's scope — the same operations as ``compute_factors``, which is
+    what makes the factors bit-identical to it (pinned in tests).
+    With ``use_pallas`` a registered kernel goes through the
+    ``kernels/batched_aca`` construction entry point.
     """
     kernel_name = kernel if isinstance(kernel, str) else None
     kfn = get_kernel(kernel) if isinstance(kernel, str) else kernel
@@ -282,27 +311,50 @@ def compute_factors_device(tree: ClusterTree, plan: HMatrixPlan,
     for level, level_blocks in plan.aca_levels.items():
         rows = jnp.asarray(level_blocks[:, 0])
         cols = jnp.asarray(level_blocks[:, 1])
-        if kernel_name is not None and kernel_name in KERNELS:
-            if use_pallas:
-                from repro.kernels.batched_aca.ops import batched_aca_level
-                fn = partial(batched_aca_level, tree.points, rows, cols,
-                             level, kernel_name, k)
-            else:
-                from repro.kernels.batched_aca.ref import batched_aca_level_ref
-                fn = partial(batched_aca_level_ref, tree.points, rows, cols,
-                             level, kernel_name, k)
+        if use_pallas and kernel_name in KERNELS:
+            fn = partial(_aca_level_pallas, tree.points, rows, cols,
+                         level=level, kernel_name=kernel_name, k=k)
         else:
-            # custom callable kernels: same gather + the shared batched
-            # ACA executable (no registered name to dispatch on)
-            m = tree.n_pad >> level
+            def fn(level=level, rows=rows, cols=cols):
+                rp, cp = _level_points(tree.points, rows, cols, level=level)
+                return _level_aca(rp, cp, level=level, kernel=kfn, k=k)
 
-            def fn(level=level, rows=rows, cols=cols, m=m):
-                pts = tree.points.reshape(1 << level, m, -1)
-                return batched_aca(pts[rows], pts[cols], kfn, k)
-
-        factors[level] = _contained_stage(f"build:factors:{level}", fn,
-                                          chaos_spec, retry, rng, counters)
+        with jax.profiler.TraceAnnotation(f"{SPAN}.dispatch.L{level}"):
+            factors[level] = _contained_stage(f"build:factors:{level}", fn,
+                                              chaos_spec, retry, rng,
+                                              counters)
     return factors
+
+
+# One level group's launch.  Each is jitted with the level static, so its
+# device operations carry ``hmatrix.build.aca.L{level}/...`` (a scope
+# opened around an eager call would not reach a jitted callee).  The
+# shapes differ per level, so this is still one program per level.
+
+
+@partial(jax.jit, static_argnames=("level",))
+def _level_points(points, rows, cols, *, level: int):
+    """The level's row and column cluster points, (B, m, d) each."""
+    with jax.named_scope(f"{ACA_SCOPE}.L{level}/gather"):
+        pts = points.reshape(1 << level, points.shape[0] >> level, -1)
+        return pts[rows], pts[cols]
+
+
+@partial(jax.jit, static_argnames=("level", "kernel", "k"))
+def _level_aca(row_pts, col_pts, *, level: int, kernel: Callable, k: int):
+    """The shared ``batched_aca`` program, inlined under the level's
+    scope: the same operations, so the factors are bit-identical to the
+    host path's (``compute_factors``)."""
+    with jax.named_scope(f"{ACA_SCOPE}.L{level}/aca"):
+        return batched_aca(row_pts, col_pts, kernel, k)
+
+
+@partial(jax.jit, static_argnames=("level", "kernel_name", "k"))
+def _aca_level_pallas(points, rows, cols, *, level: int, kernel_name: str,
+                      k: int):
+    from repro.kernels.batched_aca.ops import batched_aca_level
+    with jax.named_scope(f"{ACA_SCOPE}.L{level}"):
+        return batched_aca_level(points, rows, cols, level, kernel_name, k)
 
 
 @partial(jax.jit, static_argnames=("c_leaf", "kernel"))
@@ -410,26 +462,32 @@ def build_hmatrix_device_report(
     chaos_spec, retry, rng = _resolve_containment(chaos)
     counters = _fresh_counters()
 
+    annotate = jax.profiler.TraceAnnotation
     t0 = time.perf_counter()
-    spts, perm, bb_min, bb_max, meta = _contained_stage(
-        "build:plan",
-        lambda: _plan_program(coords, n_pad=n_pad, n_levels=n_levels,
-                              eta=float(eta)),
-        chaos_spec, retry, rng, counters)
-    plan = _assemble_plan(jax.device_get(meta), c_leaf, n_pad, n_levels,
-                          float(eta))
-    tree = ClusterTree(points=spts, perm=perm, n=n, n_pad=n_pad,
-                       c_leaf=c_leaf, n_levels=n_levels,
-                       bb_min=bb_min, bb_max=bb_max)
+    with annotate(f"{SPAN}.plan"):
+        spts, perm, bb_min, bb_max, meta = _contained_stage(
+            "build:plan",
+            lambda: _plan_program(coords, n_pad=n_pad, n_levels=n_levels,
+                                  eta=float(eta)),
+            chaos_spec, retry, rng, counters)
+        with annotate(f"{SPAN}.fetch"):
+            plan = _assemble_plan(jax.device_get(meta), c_leaf, n_pad,
+                                  n_levels, float(eta))
+        tree = ClusterTree(points=spts, perm=perm, n=n, n_pad=n_pad,
+                           c_leaf=c_leaf, n_levels=n_levels,
+                           bb_min=bb_min, bb_max=bb_max)
     t1 = time.perf_counter()
 
     factors = None
-    if precompute:
-        raw = compute_factors_device(tree, plan, kernel, k,
-                                     use_pallas=use_pallas,
-                                     chaos=chaos, _counters=counters)
-        jax.block_until_ready(raw)
-        factors = FactorStore.from_factors(raw, plan=plan)
+    with annotate(f"{SPAN}.factors"):
+        if precompute:
+            raw = compute_factors_device(tree, plan, kernel, k,
+                                         use_pallas=use_pallas,
+                                         chaos=chaos, _counters=counters)
+            with annotate(f"{SPAN}.wait"):
+                jax.block_until_ready(raw)
+            with annotate(f"{SPAN}.store"):
+                factors = FactorStore.from_factors(raw, plan=plan)
     t2 = time.perf_counter()
 
     recompress_s = 0.0

@@ -18,6 +18,15 @@ All batch groups have static shapes, so the whole application is a single
 jitted program.  Set ``use_pallas=True`` to route the hot loops through the
 Pallas TPU kernels (validated against these jnp paths in tests).
 ``make_matvec`` is the single-vector convenience wrapper.
+
+Every operation of a product carries a named scope in its metadata, so a
+profiler trace attributes device time to the apply's parts:
+``hmatrix.apply/permute_in``, ``hmatrix.apply/lowrank.L{level}/{gather,
+contract,scatter}`` (``aca`` too in NP mode), ``hmatrix.apply/dense/
+{gather,kernel,contract,scatter}`` and ``hmatrix.apply/permute_out``.
+The fused PCG and the sharded apply inline the same body, so they carry
+the same scopes.  Scopes are metadata: they change neither the numerics
+nor the compiled program.
 """
 from __future__ import annotations
 
@@ -34,6 +43,8 @@ from .block_tree import HMatrixPlan, build_block_tree
 from .clustering import ClusterTree, build_cluster_tree, permute_from_tree, permute_to_tree
 from .factor_store import FactorStore, recompress_store
 from .geometry import get_kernel
+
+APPLY_SCOPE = "hmatrix.apply"
 
 
 @dataclass(frozen=True)
@@ -155,15 +166,18 @@ def _aca_level_apply(tree, level, blocks, U, V, x_pad, z_pad, use_pallas):
     m = tree.n_pad >> level
     r = x_pad.shape[1]
     rows, cols = jnp.asarray(blocks[:, 0]), jnp.asarray(blocks[:, 1])
-    x_blk = x_pad.reshape(1 << level, m, r)[cols]              # (B, m, R)
-    if use_pallas:
-        from repro.kernels.batched_aca.ops import batched_lowrank_matmat
-        y = batched_lowrank_matmat(U, V, x_blk)                # U (V^T X)
-    else:
-        t = jnp.einsum("bmk,bmr->bkr", V, x_blk)               # V^T X
-        y = jnp.einsum("bmk,bkr->bmr", U, t)                   # U T
-    zl = jnp.zeros((1 << level, m, r), x_pad.dtype).at[rows].add(y)
-    return z_pad + zl.reshape(-1, r)
+    with jax.named_scope("gather"):
+        x_blk = x_pad.reshape(1 << level, m, r)[cols]          # (B, m, R)
+    with jax.named_scope("contract"):
+        if use_pallas:
+            from repro.kernels.batched_aca.ops import batched_lowrank_matmat
+            y = batched_lowrank_matmat(U, V, x_blk)            # U (V^T X)
+        else:
+            t = jnp.einsum("bmk,bmr->bkr", V, x_blk)           # V^T X
+            y = jnp.einsum("bmk,bkr->bmr", U, t)               # U T
+    with jax.named_scope("scatter"):
+        zl = jnp.zeros((1 << level, m, r), x_pad.dtype).at[rows].add(y)
+        return z_pad + zl.reshape(-1, r)
 
 
 def _dense_apply_points(points, plan, kernel, x_pad, z_pad, use_pallas,
@@ -176,20 +190,29 @@ def _dense_apply_points(points, plan, kernel, x_pad, z_pad, use_pallas,
     n_leaf = plan.n_pad // c
     rows, cols = jnp.asarray(blocks[:, 0]), jnp.asarray(blocks[:, 1])
     pts = points.reshape(n_leaf, c, -1)
-    x_blk = x_pad.reshape(n_leaf, c, r)[cols]                  # (B, c, R)
+    with jax.named_scope("gather"):
+        x_blk = x_pad.reshape(n_leaf, c, r)[cols]              # (B, c, R)
+        if dense is None:
+            pts_r, pts_c = pts[rows], pts[cols]
     if dense is not None:
         # stored dense leaves (FactorStore.dense): a straight batched MXU
         # contraction — no kernel regeneration, so no Pallas branch needed
-        y = jnp.einsum("bij,bjr->bir", dense, x_blk)
+        with jax.named_scope("contract"):
+            y = jnp.einsum("bij,bjr->bir", dense, x_blk)
     elif use_pallas:
         from repro.kernels.batched_dense_matvec.ops import batched_kernel_matmat
-        y = batched_kernel_matmat(pts[rows], pts[cols], x_blk,
-                                  tree_kernel_name(kernel))
+        # one kernel regenerates each block and contracts it
+        with jax.named_scope("kernel"):
+            y = batched_kernel_matmat(pts_r, pts_c, x_blk,
+                                      tree_kernel_name(kernel))
     else:
-        a = kernel(pts[rows], pts[cols])                       # (B, c, c)
-        y = jnp.einsum("bij,bjr->bir", a, x_blk)
-    zl = jnp.zeros((n_leaf, c, r), x_pad.dtype).at[rows].add(y)
-    return z_pad + zl.reshape(-1, r)
+        with jax.named_scope("kernel"):
+            a = kernel(pts_r, pts_c)                           # (B, c, c)
+        with jax.named_scope("contract"):
+            y = jnp.einsum("bij,bjr->bir", a, x_blk)
+    with jax.named_scope("scatter"):
+        zl = jnp.zeros((n_leaf, c, r), x_pad.dtype).at[rows].add(y)
+        return z_pad + zl.reshape(-1, r)
 
 
 def tree_kernel_name(kernel: Callable) -> str:
@@ -233,23 +256,33 @@ def apply_in_tree_order(tree: ClusterTree, plan: HMatrixPlan, kernel: Callable,
     z_pad : jnp.ndarray, shape (n_pad, R)
         ``H @ x_pad`` in tree ordering.
     """
-    z_pad = jnp.zeros_like(x_pad)
-    for level, blocks in plan.aca_levels.items():
-        if factors is not None:
-            U, V = factors[level]
-        else:
-            m = tree.n_pad >> level
-            rp = points.reshape(1 << level, m, -1)[jnp.asarray(blocks[:, 0])]
-            cp = points.reshape(1 << level, m, -1)[jnp.asarray(blocks[:, 1])]
-            if use_pallas:
-                from repro.kernels.batched_aca.ops import batched_aca_pallas
-                U, V = batched_aca_pallas(rp, cp, tree_kernel_name(kernel), k)
-            else:
-                U, V = batched_aca(rp, cp, kernel, k)
-        z_pad = _aca_level_apply(tree, level, blocks, U, V, x_pad, z_pad,
-                                 use_pallas)
-    return _dense_apply_points(points, plan, kernel, x_pad, z_pad, use_pallas,
-                               dense=getattr(factors, "dense", None))
+    with jax.named_scope(APPLY_SCOPE):
+        z_pad = jnp.zeros_like(x_pad)
+        for level, blocks in plan.aca_levels.items():
+            with jax.named_scope(f"lowrank.L{level}"):
+                if factors is not None:
+                    U, V = factors[level]
+                else:
+                    with jax.named_scope("aca"):
+                        U, V = _regenerate_factors(tree, level, blocks, kernel,
+                                                   k, use_pallas, points)
+                z_pad = _aca_level_apply(tree, level, blocks, U, V, x_pad,
+                                         z_pad, use_pallas)
+        with jax.named_scope("dense"):
+            return _dense_apply_points(points, plan, kernel, x_pad, z_pad,
+                                       use_pallas,
+                                       dense=getattr(factors, "dense", None))
+
+
+def _regenerate_factors(tree, level, blocks, kernel, k, use_pallas, points):
+    """NP mode: one level group's ACA factors, recomputed in the product."""
+    m = tree.n_pad >> level
+    rp = points.reshape(1 << level, m, -1)[jnp.asarray(blocks[:, 0])]
+    cp = points.reshape(1 << level, m, -1)[jnp.asarray(blocks[:, 1])]
+    if use_pallas:
+        from repro.kernels.batched_aca.ops import batched_aca_pallas
+        return batched_aca_pallas(rp, cp, tree_kernel_name(kernel), k)
+    return batched_aca(rp, cp, kernel, k)
 
 
 def make_apply(hm: HMatrix, use_pallas: bool = False, mesh=None,
@@ -300,10 +333,12 @@ def make_apply(hm: HMatrix, use_pallas: bool = False, mesh=None,
 
     @jax.jit
     def _apply(points, factors, x):
-        x_pad = permute_to_tree(tree, x)                       # (n_pad, R)
+        with jax.named_scope(f"{APPLY_SCOPE}/permute_in"):
+            x_pad = permute_to_tree(tree, x)                   # (n_pad, R)
         z_pad = apply_in_tree_order(tree, plan, kernel, k, use_pallas,
                                     points, factors, x_pad)
-        return permute_from_tree(tree, z_pad)
+        with jax.named_scope(f"{APPLY_SCOPE}/permute_out"):
+            return permute_from_tree(tree, z_pad)
 
     def apply(x: jnp.ndarray) -> jnp.ndarray:
         if x.ndim not in (1, 2) or x.shape[0] != tree.n:
@@ -318,6 +353,8 @@ def make_apply(hm: HMatrix, use_pallas: bool = False, mesh=None,
             return jnp.zeros_like(x)
         return _apply(tree.points, hm.factors, x)
 
+    # the program of an (N, R) panel product, lowered as jax.jit lowers it
+    apply.lower = lambda x: _apply.lower(tree.points, hm.factors, x)
     return apply
 
 

@@ -55,6 +55,15 @@ budget:
   staging-buffer pool (one buffer per pacer slot), zero-copy pack/pad,
   the launch call, and resolving the chunk's futures.
 
+The scheduler and the fetch open profiler spans (``jax.profiler.
+TraceAnnotation``): ``hmatrix.serve.pace_wait`` while the pacer blocks,
+``hmatrix.serve.pack`` while a panel is packed, ``hmatrix.serve.launch``
+around the upload and dispatch (arguments ``tenant``, ``requests``,
+``width``, ``queued_ms_sum``, ``queued_ms_max``: the panel's requests'
+waits from enqueue to launch), and ``hmatrix.serve.fetch`` around the
+one blocking fetch of a panel.  They cost next to nothing while no
+profiler runs.
+
 Futures resolve in submission order (panels launch FIFO; columns within a
 panel preserve arrival order) and — because the sync path packs identical
 panels via the same width buckets — results are bit-identical to
@@ -99,6 +108,9 @@ def _strict_transfer_guard():
         stack.enter_context(jax.transfer_guard_device_to_host("disallow"))
         return stack
     return contextlib.nullcontext()
+
+# prefix of the runtime's profiler spans (docs/ARCHITECTURE.md, Tracing)
+SPAN = "hmatrix.serve"
 
 # width fractions of the full panel pre-compiled for partial flushes
 _BUCKET_FRACTIONS = (4, 2, 1)
@@ -215,8 +227,9 @@ class _PanelRecord:
             if self._exc is not None:
                 raise self._exc
             if self._host is None:
-                # hlint: disable=host-sync -- THE documented lazy fetch: one blocking transfer per panel, cached for every column future
-                out = np.asarray(self._dev)
+                with jax.profiler.TraceAnnotation(f"{SPAN}.fetch"):
+                    # hlint: disable=host-sync -- THE documented lazy fetch: one blocking transfer per panel, cached for every column future
+                    out = np.asarray(self._dev)
                 if self._guard is not None:
                     try:
                         out = self._guard.check(out)
@@ -238,14 +251,13 @@ class PanelFuture:
     futures), and returns this request's ``(N,)`` column.
     """
 
-    __slots__ = ("_event", "_record", "_col", "_exc", "t_submit")
+    __slots__ = ("_event", "_record", "_col", "_exc")
 
     def __init__(self):
         self._event = threading.Event()
         self._record = None
         self._col = 0
         self._exc = None
-        self.t_submit = time.monotonic()
 
     def _resolve(self, record: _PanelRecord, col: int):
         self._record, self._col = record, col
@@ -307,24 +319,27 @@ class LaunchPacer:
         launch's ``on_retire(elapsed_s, ok)`` callback (straggler
         accounting) — exceptions from it are contained, like device ones.
         """
-        while len(self._inflight) >= self.max_inflight:
-            dev, t_commit, on_retire = self._inflight.pop(0)
-            ok = True
-            try:
-                # hlint: disable=host-sync -- pacing backpressure by design: block on the OLDEST launch only when the inflight window is full
-                jax.block_until_ready(dev)
-            except Exception:
-                # async dispatch defers device failures to the first
-                # block: the panel's awaiters hit the same error at
-                # their np.asarray fetch — do not let it kill the
-                # scheduler thread (pending requests would strand and
-                # close() would deadlock)
-                ok = False
-            if on_retire is not None:
+        if len(self._inflight) < self.max_inflight:
+            return
+        with jax.profiler.TraceAnnotation(f"{SPAN}.pace_wait"):
+            while len(self._inflight) >= self.max_inflight:
+                dev, t_commit, on_retire = self._inflight.pop(0)
+                ok = True
                 try:
-                    on_retire(time.monotonic() - t_commit, ok)
+                    # hlint: disable=host-sync -- pacing backpressure by design: block on the OLDEST launch only when the inflight window is full
+                    jax.block_until_ready(dev)
                 except Exception:
-                    pass                # accounting must not kill the scheduler
+                    # async dispatch defers device failures to the first
+                    # block: the panel's awaiters hit the same error at
+                    # their np.asarray fetch — do not let it kill the
+                    # scheduler thread (pending requests would strand and
+                    # close() would deadlock)
+                    ok = False
+                if on_retire is not None:
+                    try:
+                        on_retire(time.monotonic() - t_commit, ok)
+                    except Exception:
+                        pass                # accounting must not kill the scheduler
 
     def commit(self, dev, on_retire=None):
         """Record one freshly dispatched launch (scheduler thread only)."""
@@ -356,6 +371,9 @@ class PanelLane:
     store so the owning runtime can do byte accounting (``nbytes()``)
     and drive the memory tier (spill cold tenants, reload before
     launch; see ``MultiTenantRuntime``).
+
+    ``name`` (the tenant's) labels the lane's ``hmatrix.serve.launch``
+    spans.
     """
 
     def __init__(self, n: int, max_batch: int, launch: Callable,
@@ -363,7 +381,8 @@ class PanelLane:
                  fallback: Callable | None = None,
                  guard_outputs: bool = False,
                  on_fallback: Callable | None = None,
-                 store=None):
+                 store=None, name: str = ""):
+        self.name = name
         self.n = int(n)
         self.max_batch = int(max_batch)
         self.widths = panel_width_buckets(self.max_batch, n_dev)
@@ -396,15 +415,21 @@ class PanelLane:
         """
         w = width_for(len(chunk), self.widths)
         buf = self._staging[self._buf]
-        for j, (q, _, _) in enumerate(chunk):
-            buf[:, j] = q
-        if len(chunk) < w:
-            buf[:, len(chunk):w] = 0.0              # stale pad from last reuse
+        with jax.profiler.TraceAnnotation(f"{SPAN}.pack"):
+            for j, (q, _, _) in enumerate(chunk):
+                buf[:, j] = q
+            if len(chunk) < w:
+                buf[:, len(chunk):w] = 0.0          # stale pad from last reuse
         t0 = time.monotonic()
+        queued_ms = [1e3 * (t0 - t) for _, _, t in chunk]
+        span = jax.profiler.TraceAnnotation(
+            f"{SPAN}.launch", tenant=self.name, requests=len(chunk), width=w,
+            queued_ms_sum=sum(queued_ms),
+            queued_ms_max=max(queued_ms, default=0.0))
         try:
             # jnp.asarray on CPU can zero-copy ALIAS the staging buffer —
             # safe ONLY because of the pacing invariant (see LaunchPacer).
-            with _strict_transfer_guard():
+            with span, _strict_transfer_guard():
                 dev = self._launch(jnp.asarray(buf[:, :w]))
         except Exception as exc:
             # _buf deliberately NOT advanced: nothing holds this buffer (a

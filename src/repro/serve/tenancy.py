@@ -291,7 +291,7 @@ class _Tenant:
                               n_dev=spec.n_dev, slots=slots,
                               injector=injector, fallback=spec.fallback,
                               guard_outputs=guard, on_fallback=on_fallback,
-                              store=spec.store)
+                              store=spec.store, name=name)
         self.res = (LaneResilience(resilience, name)
                     if resilience is not None else None)
         self.pending: list = []         # [(np vector, PanelFuture, t_arrival)]
