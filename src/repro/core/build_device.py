@@ -84,11 +84,12 @@ SPAN = "hmatrix.build"
 def _plan_program(coords, *, n_pad: int, n_levels: int, eta: float):
     """Sort + boxes + block-cluster-tree traversal as ONE device program.
 
-    Returns ``(sorted_pts, perm, bb_min, bb_max, meta)`` where ``meta`` is
-    a packed int32 vector: ``n_levels + 2`` counts (admissible blocks per
-    level, then dense leaves) followed by the capacity-padded (row, col)
-    id arrays per level (valid prefixes per the counts) — ONE array to
-    fetch, sliced on host by :func:`_assemble_plan`.
+    Returns ``(sorted_pts, perm, iperm, bb_min, bb_max, meta)`` where
+    ``iperm`` is the inverse permutation (it stays on the device) and
+    ``meta`` is a packed int32 vector: ``n_levels + 2`` counts (admissible
+    blocks per level, then dense leaves) followed by the capacity-padded
+    (row, col) id arrays per level (valid prefixes per the counts) — ONE
+    array to fetch, sliced on host by :func:`_assemble_plan`.
 
     Every frontier has static capacity ``4**level`` (the balanced tree's
     worst case); validity is carried as a count + mask so the whole
@@ -98,12 +99,12 @@ def _plan_program(coords, *, n_pad: int, n_levels: int, eta: float):
     the emitted plan comparable array-for-array with the host oracle.
     """
     with jax.named_scope(f"{PLAN_SCOPE}/morton_sort"):
-        spts, perm = _morton_sort(coords, n_pad)
+        spts, perm, iperm = _morton_sort(coords, n_pad)
     with jax.named_scope(f"{PLAN_SCOPE}/bbox"):
         mins, maxs = _boxes(spts, n_levels)
     with jax.named_scope(f"{PLAN_SCOPE}/blocktree"):
         meta = _block_tree(mins, maxs, n_levels, eta)
-    return spts, perm, tuple(mins), tuple(maxs), meta
+    return spts, perm, iperm, tuple(mins), tuple(maxs), meta
 
 
 def _morton_sort(coords, n_pad: int):
@@ -122,7 +123,8 @@ def _morton_sort(coords, n_pad: int):
     # sort (index as final tiebreaker: a total order, so the unstable
     # comparator has exactly one valid output) when ties exist — both
     # branches reproduce the host's stable ``lexsort((lo, hi))``
-    # permutation bit-for-bit.
+    # permutation bit-for-bit.  Each branch also returns the inverse
+    # permutation: the rank IS it, and only the tie branch pays a scatter.
     idx = jax.lax.iota(jnp.int32, n)
     shi = jax.lax.sort(code_hi, is_stable=False)
     hi_ties = (shi[1:] == shi[:-1]).any()
@@ -130,19 +132,19 @@ def _morton_sort(coords, n_pad: int):
     def _perm_by_rank(_):
         pos = jnp.searchsorted(shi, code_hi,
                                method="scan").astype(jnp.int32)
-        return jnp.zeros((n,), jnp.int32).at[pos].set(idx)
+        return jnp.zeros((n,), jnp.int32).at[pos].set(idx), pos
 
     def _perm_full_sort(_):
         _, _, p = jax.lax.sort((code_hi, code_lo, idx),
                                num_keys=3, is_stable=False)
-        return p
+        return p, jnp.zeros((n,), jnp.int32).at[p].set(idx)
 
-    perm = jax.lax.cond(hi_ties, _perm_full_sort, _perm_by_rank, None)
+    perm, iperm = jax.lax.cond(hi_ties, _perm_full_sort, _perm_by_rank, None)
     spts = coords[perm]
     if n_pad > n:
         spts = jnp.concatenate(
             [spts, jnp.broadcast_to(spts[-1], (n_pad - n, d))], axis=0)
-    return spts, perm
+    return spts, perm, iperm
 
 
 def _boxes(spts, n_levels: int):
@@ -465,7 +467,7 @@ def build_hmatrix_device_report(
     annotate = jax.profiler.TraceAnnotation
     t0 = time.perf_counter()
     with annotate(f"{SPAN}.plan"):
-        spts, perm, bb_min, bb_max, meta = _contained_stage(
+        spts, perm, iperm, bb_min, bb_max, meta = _contained_stage(
             "build:plan",
             lambda: _plan_program(coords, n_pad=n_pad, n_levels=n_levels,
                                   eta=float(eta)),
@@ -473,8 +475,8 @@ def build_hmatrix_device_report(
         with annotate(f"{SPAN}.fetch"):
             plan = _assemble_plan(jax.device_get(meta), c_leaf, n_pad,
                                   n_levels, float(eta))
-        tree = ClusterTree(points=spts, perm=perm, n=n, n_pad=n_pad,
-                           c_leaf=c_leaf, n_levels=n_levels,
+        tree = ClusterTree(points=spts, perm=perm, iperm=iperm, n=n,
+                           n_pad=n_pad, c_leaf=c_leaf, n_levels=n_levels,
                            bb_min=bb_min, bb_max=bb_max)
     t1 = time.perf_counter()
 

@@ -39,6 +39,9 @@ class ClusterTree:
     points:   (N_pad, d) Morton-sorted (and padded) coordinates.
     perm:     (N,) permutation from original ordering to sorted ordering
               (``sorted[i] = original[perm[i]]``).
+    iperm:    (N,) inverse of ``perm`` (``iperm[perm[i]] = i``): ``iperm[j]``
+              is the tree position of original point ``j``, so moving a
+              tree-ordered vector back to the original order is a gather.
     n:        true number of points (<= N_pad).
     n_pad:    padded size (power of two).
     c_leaf:   leaf cluster size (power of two).
@@ -49,6 +52,7 @@ class ClusterTree:
 
     points: jnp.ndarray
     perm: jnp.ndarray
+    iperm: jnp.ndarray
     n: int
     n_pad: int
     c_leaf: int
@@ -113,8 +117,9 @@ def build_cluster_tree(coords: jnp.ndarray, c_leaf: int = 256) -> ClusterTree:
         sorted_pts = jnp.concatenate([sorted_pts, pad], axis=0)
     n_levels = int(np.log2(n_pad // c_leaf))
     bb_min, bb_max = _level_bounding_boxes(sorted_pts, n_levels)
-    return ClusterTree(points=sorted_pts, perm=perm, n=n, n_pad=n_pad,
-                       c_leaf=c_leaf, n_levels=n_levels,
+    iperm = jnp.argsort(perm).astype(jnp.int32)
+    return ClusterTree(points=sorted_pts, perm=perm, iperm=iperm, n=n,
+                       n_pad=n_pad, c_leaf=c_leaf, n_levels=n_levels,
                        bb_min=bb_min, bb_max=bb_max)
 
 
@@ -127,6 +132,8 @@ def permute_to_tree(tree: ClusterTree, x: jnp.ndarray) -> jnp.ndarray:
 
 
 def permute_from_tree(tree: ClusterTree, z_pad: jnp.ndarray) -> jnp.ndarray:
-    """Padded tree-ordered vector -> original ordering (drops the pad)."""
-    z = jnp.zeros((tree.n,) + z_pad.shape[1:], z_pad.dtype)
-    return z.at[tree.perm].set(z_pad[: tree.n])
+    """Padded tree-ordered vector -> original ordering (drops the pad).
+
+    A row gather by the inverse permutation; every ``iperm`` value is below
+    ``n``, so the padded tail is never read."""
+    return z_pad[tree.iperm]

@@ -19,6 +19,7 @@ from repro.core import (build_hmatrix, build_hmatrix_device,
                         compute_factors_device, eval_dense_leaves, halton,
                         make_apply)
 from repro.core.geometry import get_kernel
+from repro.core.morton import morton_encode
 from repro.kernels.batched_aca.ops import batched_aca_level
 from repro.kernels.batched_aca.ref import batched_aca_level_ref
 from repro.solve import make_solver
@@ -81,6 +82,8 @@ def test_device_plan_matches_host_exactly(case):
     host, dev = _build_pair(case)
     np.testing.assert_array_equal(np.asarray(dev.tree.perm),
                                   np.asarray(host.tree.perm))
+    np.testing.assert_array_equal(np.asarray(dev.tree.iperm),
+                                  np.asarray(host.tree.iperm))
     np.testing.assert_array_equal(np.asarray(dev.tree.points),
                                   np.asarray(host.tree.points))
     for lvl in range(host.tree.n_levels + 1):
@@ -89,6 +92,26 @@ def test_device_plan_matches_host_exactly(case):
         np.testing.assert_array_equal(np.asarray(dev.tree.bb_max[lvl]),
                                       np.asarray(host.tree.bb_max[lvl]))
     _assert_plans_equal(host.plan, dev.plan)
+
+
+@pytest.mark.parametrize("case,ties", [("halton2d", False),
+                                       ("duplicates", True)],
+                         ids=["rank-branch", "tie-branch"])
+def test_device_inverse_permutation_on_both_sort_branches(case, ties):
+    """The plan's sort recovers the permutation by rank unless the high
+    Morton halves tie (duplicated points), when it falls back to the full
+    sort; the inverse permutation must be exact on both branches."""
+    factory, c_leaf, eta = CASES[case]
+    pts = jnp.asarray(factory())
+    lo, hi = pts.min(axis=0), pts.max(axis=0)
+    code_hi, _ = morton_encode((pts - lo) / jnp.maximum(hi - lo, 1e-30))
+    n = pts.shape[0]
+    assert (np.unique(np.asarray(code_hi)).size < n) == ties
+    hm = build_hmatrix_device(pts, c_leaf=c_leaf, eta=eta)
+    perm, iperm = np.asarray(hm.tree.perm), np.asarray(hm.tree.iperm)
+    assert iperm.dtype == np.int32
+    np.testing.assert_array_equal(iperm, np.argsort(perm))
+    np.testing.assert_array_equal(perm[iperm], np.arange(n))
 
 
 def test_single_leaf_degenerates_to_one_dense_block():

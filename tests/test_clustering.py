@@ -1,6 +1,7 @@
 """Cluster tree invariants C1-C4 (paper §2.1) + bounding boxes (§5.3)."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core.clustering import build_cluster_tree, next_pow2, permute_from_tree, permute_to_tree
@@ -38,11 +39,22 @@ def test_bounding_boxes_match_bruteforce(rng):
             np.testing.assert_allclose(np.asarray(tree.bb_max[lvl][i]), seg.max(0), rtol=1e-6)
 
 
-def test_permutation_roundtrip(rng):
-    pts = jnp.asarray(rng.rand(300, 3).astype(np.float32))
+@pytest.mark.parametrize("n,r", [(300, None), (300, 5), (512, 3)],
+                         ids=["padded-vector", "padded-panel",
+                              "unpadded-panel"])
+def test_permutation_roundtrip(rng, n, r):
+    pts = jnp.asarray(rng.rand(n, 3).astype(np.float32))
     tree = build_cluster_tree(pts, c_leaf=64)
-    x = jnp.asarray(rng.randn(300).astype(np.float32))
+    assert (tree.n_pad > n) == (n == 300)
+    perm, iperm = np.asarray(tree.perm), np.asarray(tree.iperm)
+    assert iperm.dtype == np.int32
+    np.testing.assert_array_equal(perm[iperm], np.arange(n))
+    np.testing.assert_array_equal(iperm[perm], np.arange(n))
+    shape = (n,) if r is None else (n, r)
+    x = jnp.asarray(rng.randn(*shape).astype(np.float32))
     xp = permute_to_tree(tree, x)
-    assert xp.shape[0] == tree.n_pad
+    assert xp.shape == (tree.n_pad,) + shape[1:]
+    # a nonzero padded tail must not leak into the original order
+    xp = xp.at[n:].set(1e6)
     x2 = permute_from_tree(tree, xp)
-    np.testing.assert_allclose(np.asarray(x2), np.asarray(x), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(x2), np.asarray(x))

@@ -16,7 +16,7 @@ import pytest
 
 from repro.core import (build_hmatrix, build_hmatrix_device_report,
                         compute_factors_device, halton, make_apply)
-from repro.core import build_device
+from repro.core import build_device, hmatrix
 from repro.kernels.batched_aca.ref import batched_aca_level_ref
 from repro.serve.tenancy import MultiTenantRuntime, apply_tenant
 
@@ -98,6 +98,38 @@ def test_scopes_leave_apply_program_and_result_unchanged(points, panel,
         program0 = _instructions(plain.lower(panel).compile().as_text())
     assert np.array_equal(z, z0)
     assert program == program0
+
+
+def _scope_primitives(text: str, scope: str) -> set:
+    """The primitives whose locations in the lowered program lie under
+    ``scope``."""
+    return set(re.findall(r'loc\("[^"]*' + re.escape(scope) + r'/([a-z_]+)"',
+                          text))
+
+
+def _scatter_back(tree, z_pad):
+    """The permutation back as a row scatter by ``perm``."""
+    z = jnp.zeros((tree.n,) + z_pad.shape[1:], z_pad.dtype)
+    return z.at[tree.perm].set(z_pad[: tree.n])
+
+
+@pytest.mark.parametrize("precompute", [True, False], ids=["P", "NP"])
+def test_apply_permutes_back_by_gather(points, panel, precompute,
+                                       monkeypatch):
+    hm = build_hmatrix(points, k=K, c_leaf=C_LEAF, precompute=precompute)
+    gathered = make_apply(hm)
+    ops = _scope_primitives(gathered.lower(panel).as_text(debug_info=True),
+                            "hmatrix.apply/permute_out")
+    assert "gather" in ops and not any("scatter" in p for p in ops), ops
+    with monkeypatch.context() as m:
+        m.setattr(hmatrix, "permute_from_tree", _scatter_back)
+        scattered = make_apply(hm)
+        old = _scope_primitives(
+            scattered.lower(panel).as_text(debug_info=True),
+            "hmatrix.apply/permute_out")
+        assert "scatter" in old, old
+        z0 = np.asarray(scattered(panel))
+    assert np.array_equal(np.asarray(gathered(panel)), z0)
 
 
 def test_build_plan_and_aca_ops_carry_scopes(points):
