@@ -8,7 +8,8 @@ process (set-up is paid once per seed, compiles once).
 
 With ``--rates`` (a serving cell) one set-up serves a window of
 ``--seconds`` at each offered rate in turn and prints its p95 latency,
-failures and backlog: the sweep that finds the knee.
+failures, backlog and the widths it launched: the sweep that finds the
+knee.
 
 Without ``--control`` each seed runs the cell's loop as ``run.py`` does
 (set-up, a window of ``--seconds``, release, check) and prints the
@@ -64,12 +65,17 @@ def sweep(cell, seed: int, seconds: float, rates) -> int:
         t0 = time.perf_counter()
         got = loop.window(seconds, dict(cell.traffic, rate_per_s=rate))
         stats = loop.tenant.stats()
+        widths = loop.counters["launched_widths"]
         print(json.dumps({"workload": cell.name, "rate_per_s": rate,
                           **got, "attempted": loop.attempted,
                           "failed": loop.failed,
                           "drain_past_window_s":
                               time.perf_counter() - t0 - seconds,
                           "max_queue_depth": stats["max_queue_depth"],
+                          "launches": len(widths),
+                          "mean_width": sum(widths) / max(len(widths), 1),
+                          "top_bucket_pct": suite.reader(
+                              "serve_top_bucket_pct", cell.root)(loop),
                           "generator_lag_p95_s":
                               loop.counters["generator_lag_p95_s"]}),
               flush=True)

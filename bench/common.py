@@ -70,10 +70,9 @@ class Reservoir:
             self.items[slot] = item
 
 
-@partial(jax.jit, static_argnames=("n", "cols", "count"))
-def _normal_panels(key, *, n: int, cols: int, count: int):
-    return tuple(jax.random.normal(k, (n, cols), jnp.float32)
-                 for k in jax.random.split(key, count))
+@partial(jax.jit, static_argnames=("n", "cols"))
+def _normal_panel(key, *, n: int, cols: int):
+    return jax.random.normal(key, (n, cols), jnp.float32)
 
 
 def design_points(cell):
@@ -118,8 +117,10 @@ class Loop:
         return float(self.limits[name]["limit"])
 
     def panel_pool(self, key, count: int, cols: int):
-        return list(_normal_panels(key, n=self.cfg["n_points"], cols=cols,
-                                   count=count))
+        # one small program called per panel: a program with ``count``
+        # outputs takes seconds to trace and load at a pool of hundreds
+        return [_normal_panel(k, n=self.cfg["n_points"], cols=cols)
+                for k in jax.random.split(key, count)]
 
     def dense_apply(self, x):
         """The plain reference's product with the design, at HIGHEST."""

@@ -1,5 +1,7 @@
 """Closed loop of ``make_apply(hm)(X)`` over a seeded pool of (N, R)
-panels; each product is waited on before the next.
+panels, taken in order (the order goes on across windows, so the
+control's one-product windows walk the pool too); each product is waited
+on before the next.
 
 Traffic keys: ``cols`` (R), ``pool`` (panels in the pool), ``check``
 (products of the window compared, drawn from the seed, stacked as one
@@ -10,6 +12,7 @@ import gc
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from bench import common, reference
@@ -31,12 +34,13 @@ class Loop(common.Loop):
         for x in self.pool[:2]:
             jax.block_until_ready(self.apply(x))
         self.sample = common.Reservoir(t["check"], self.seed)
+        self.done = 0                   # products of earlier windows
 
     def window(self, seconds: float) -> dict:
         pool, apply, n = self.pool, self.apply, 0
         t0 = time.perf_counter()
         while True:
-            i = n % len(pool)
+            i = (self.done + n) % len(pool)
             with common.span("apply"):
                 z = jax.block_until_ready(apply(pool[i]))
             n += 1
@@ -45,6 +49,7 @@ class Loop(common.Loop):
             if end - t0 >= seconds:
                 break
         self.attempted = n
+        self.done += n
         return {self.metric: self.traffic["cols"] * n / (end - t0)}
 
     def release(self):
@@ -53,11 +58,10 @@ class Loop(common.Loop):
         gc.collect()
 
     def check(self):
-        # the checked products stacked as one panel; each pool panel's
-        # reference is computed once
-        ref = {i: np.asarray(self.dense_apply(self.pool[i]))
-               for i in sorted({i for i, _ in self.memo})}
+        # the checked products stacked as one panel, and their inputs as
+        # one panel of the reference: one call of a fixed width
         z = np.concatenate([z for _, z in self.memo], axis=1)
-        want = np.concatenate([ref[i] for i, _ in self.memo], axis=1)
+        want = np.asarray(self.dense_apply(
+            jnp.concatenate([self.pool[i] for i, _ in self.memo], axis=1)))
         return [("apply_rel_err", reference.rel_err(z, want),
                  self.limit("apply_rel_err"))]

@@ -42,6 +42,7 @@ class Loop(common.Loop):
         self.sample = common.Reservoir(t["check"], self.seed)
         self.partial = common.Reservoir(t["check_partial"], self.seed, 0xba7c)
         self.counters["launched_widths"] = []
+        self.counters["max_batch"] = t["max_batch"]
 
     def window(self, seconds: float, traffic: dict | None = None) -> dict:
         t = self.traffic if traffic is None else traffic
@@ -89,6 +90,7 @@ class Loop(common.Loop):
                 keep(self.sample, k, res)
             close()
 
+        launched0 = self.tenant.stats()["panels_launched"]
         collector = threading.Thread(target=collect, daemon=True)
         collector.start()
         t0 = time.perf_counter()
@@ -109,8 +111,12 @@ class Loop(common.Loop):
         self.attempted = len(due)
         self.failed += len(due) - int(served.sum())
         self.counters["in_partial_panels"] = self.partial.seen
-        self.counters["launched_widths"] = list(
-            self.tenant.stats()["launched_widths"])
+        # the widths of this window's launches alone (set-up's and an
+        # earlier window's are left out)
+        stats = self.tenant.stats()
+        launched = stats["panels_launched"] - launched0
+        self.counters["launched_widths"] = (
+            stats["launched_widths"][-launched:] if launched else [])
         self.counters["generator_lag_p95_s"] = float(np.percentile(lag, 95))
         if not lat.size:
             raise RuntimeError("no request of the window completed")

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
+PARTS = 1 << 27      # floats of chunk partial sums a row block holds
 
 
 def _split_hi(a: jnp.ndarray) -> jnp.ndarray:
@@ -55,32 +56,47 @@ def _row_blocks(points: jnp.ndarray, block: int):
     return jnp.concatenate([points, pad]).reshape(nb, block, d)
 
 
-@partial(jax.jit, static_argnames=("kernel", "precision", "block", "chunk"))
+def _pairwise_sum(parts: jnp.ndarray) -> jnp.ndarray:
+    """Sum over the leading axis as a balanced tree of float32 adds: its
+    rounding grows with the log of the count, not with the count."""
+    while parts.shape[0] > 1:
+        if parts.shape[0] % 2:
+            parts = jnp.concatenate([parts, jnp.zeros_like(parts[:1])])
+        half = parts.shape[0] // 2
+        parts = parts[:half] + parts[half:]
+    return parts[0]
+
+
+@partial(jax.jit, static_argnames=("kernel", "precision", "chunk"))
 def dense_apply(points: jnp.ndarray, x: jnp.ndarray, *, kernel,
-                precision: str = "highest", block: int = 512,
-                chunk: int = 4096) -> jnp.ndarray:
+                precision: str = "highest", chunk: int = 256) -> jnp.ndarray:
     """``K(points, points) @ x`` for x of shape (n, R), one block of rows
     at a time so that no (n, n) matrix is ever held; ``kernel(y, y')`` is
     a function of two point blocks.
 
-    Each row's sum is split over chunks of ``chunk`` columns: one product
-    per chunk, and the chunks' sums added after.  The limits were read
-    with this order of summation, and it matters at their scale: on a TPU
-    v5e at n = 2^19, float32 references that differ only in that order
-    differ by about 4e-6, as much as the H-matrix differs from either."""
+    Each row's sum is one short product per chunk of ``chunk`` columns,
+    and the chunks' sums added as a balanced tree.  A float32 sum of n
+    terms added in sequence, as a matrix unit accumulates a long
+    contraction, rounds by about sqrt(n) ulps of the result: over chunks
+    of 4096 columns that is about 3e-6 at n = 2^19 on a TPU v5e, as much
+    as the H-matrix's own error.  Short chunks and the tree keep the
+    reference's rounding well under the numbers it is compared with;
+    the limits were read with this sum."""
     n = points.shape[0]
     nc = -(-n // chunk)
+    r = x.shape[1]
+    block = max(8, min(512, 1 << max(0, (PARTS // (nc * r)).bit_length() - 1)))
     cols = _row_blocks(points, chunk)            # padding is multiplied by 0
-    xc = jnp.zeros((nc * chunk, x.shape[1]), x.dtype).at[:n].set(x)
-    xc = xc.reshape(nc, chunk, x.shape[1])
+    xc = jnp.zeros((nc * chunk, r), x.dtype).at[:n].set(x)
+    xc = xc.reshape(nc, chunk, r)
     blocks = _row_blocks(points, block)
 
     def one(rows):
         parts = jax.vmap(lambda c, xs: dot(kernel(rows, c), xs, precision))(
             cols, xc)
-        return jnp.sum(parts, axis=0)
+        return _pairwise_sum(parts)
 
-    out = jax.lax.map(one, blocks).reshape(-1, x.shape[1])
+    out = jax.lax.map(one, blocks).reshape(-1, r)
     return out[:n]
 
 

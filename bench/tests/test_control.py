@@ -12,7 +12,8 @@ from bench import calibrate, suite
 from bench.tests import tiny
 
 SIZE = {"n_points": 16384, "c_leaf": 256}
-CELLS = ["paper2d.apply_r64", "paper2d.build", "paper2d.serve_apply"]
+CELLS = ["paper2d.apply_r64", "paper2d.build", "paper2d.serve_apply_k80",
+         "paper2d.apply_r1"]
 
 
 @pytest.mark.parametrize("cell", CELLS)

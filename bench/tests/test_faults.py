@@ -47,10 +47,11 @@ APPLY_FAULTS = {
 }
 
 
+@pytest.mark.parametrize("cell", ["paper2d.apply_r64", "paper2d.apply_r1"])
 @pytest.mark.parametrize("fault", sorted(APPLY_FAULTS))
-def test_apply_faults(root, capsys, monkeypatch, fault):
+def test_apply_faults(root, capsys, monkeypatch, fault, cell):
     wrap_apply(monkeypatch, APPLY_FAULTS[fault])
-    assert result(root, "paper2d.apply_r64", capsys)["correct"] is False
+    assert result(root, cell, capsys)["correct"] is False
 
 
 @pytest.mark.parametrize("fault", sorted(APPLY_FAULTS))
@@ -58,7 +59,7 @@ def test_serve_faults(root, capsys, monkeypatch, fault):
     """The fault in every launched panel: one slot altered, or half of the
     slots left out."""
     wrap_apply(monkeypatch, APPLY_FAULTS[fault])
-    assert result(root, "paper2d.serve_apply", capsys)["correct"] is False
+    assert result(root, "paper2d.serve_apply_k80", capsys)["correct"] is False
 
 
 def _alter_store(hm):

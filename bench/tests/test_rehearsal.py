@@ -9,7 +9,8 @@ import pytest
 from bench import run
 from bench.tests import tiny
 
-CELLS = ["paper2d.apply_r64", "paper2d.build", "paper2d.serve_apply"]
+CELLS = ["paper2d.apply_r64", "paper2d.build", "paper2d.serve_apply_k80",
+         "paper2d.apply_r1"]
 
 
 @pytest.fixture(scope="module")
@@ -114,13 +115,13 @@ def test_new_cell_from_files_alone(root, capsys):
             rate = traffic["rate_per_s"]
             return np.full(max(1, int(round(rate * seconds))), 1.0 / rate)
         """))
-    with open(f"{path}/bench/traffic/serve_apply.json") as f:
+    with open(f"{path}/bench/traffic/serve_apply_k80.json") as f:
         traffic = json.load(f)
     _write(path, "traffic/serve_even.json",
            json.dumps(dict(traffic, arrivals="even", rate_per_s=40)))
     cell = "tiny_paper2d.serve_even"
     _add_cell(path, cell, "tiny_paper2d", "serve_even",
-              names["paper2d.serve_apply"], "serve_p95_ms")
+              names["paper2d.serve_apply_k80"], "serve_p95_ms")
     rc, out = run_cell(path, cell, capsys, seed=8)
     assert rc == 0, out.err[-3000:]
     res = json.loads(out.out.strip().splitlines()[-1])
@@ -144,7 +145,7 @@ def test_reference_kernel_from_a_file(root):
     pts = suite.points("halton", path)({"n_points": 300, "dim": 2,
                                          "side": 1.0})
     x = jnp.ones((300, 2), jnp.float32)
-    z = reference.dense_apply(pts, x, kernel=k, block=128)
+    z = reference.dense_apply(pts, x, kernel=k, chunk=64)
     p = np.asarray(pts, np.float64)
     want = np.exp(-np.abs(p[:, None] - p[None]).sum(-1)) @ np.ones((300, 2))
     assert np.allclose(np.asarray(z), want, rtol=1e-5)
