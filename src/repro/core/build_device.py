@@ -20,8 +20,9 @@ exactly the part of the paper that maps onto a handful of wide launches:
 (:func:`_plan_program`) whose only host interaction is a single fetch of
 a packed ``int32`` metadata vector (block ids + per-level counts), then
 runs factor assembly as one batched fixed-rank ACA launch per admissible
-level group (paper §5.4.1 — the ``kernels/batched_aca`` construction
-entry point) — O(levels) launches instead of O(blocks) host calls.  The
+level group, or per static batch of a group too large for one launch
+(paper §5.4.1 and §6.5 — the ``kernels/batched_aca`` construction entry
+point) — O(levels) launches instead of O(blocks) host calls.  The
 result is an :class:`~repro.core.hmatrix.HMatrix` whose plan, points,
 permutation and factors are BIT-IDENTICAL to the host oracle's (pinned
 by ``tests/test_build_device.py``): the structural program performs the
@@ -66,7 +67,7 @@ from .block_tree import HMatrixPlan
 from .clustering import ClusterTree, next_pow2
 from .factor_store import FactorStore, recompress_store
 from .geometry import get_kernel, KERNELS
-from .hmatrix import HMatrix
+from .hmatrix import HMatrix, launch_batches
 from .morton import morton_encode
 
 # Names of the build's device scopes (op metadata) and host spans
@@ -74,6 +75,15 @@ from .morton import morton_encode
 PLAN_SCOPE = "hmatrix.build.plan"
 ACA_SCOPE = "hmatrix.build.aca"
 SPAN = "hmatrix.build"
+
+# A level group's ACA goes in the static batches (``launch_batches``) of a
+# float32 panel of this many columns of its blocks.  Its own working set
+# per block row is about 1.2 KB on a v5e (the (m, d) points padded to 128
+# lanes, the (k, m) carries), 4.6 times such a panel's 256 bytes, so one
+# launch holds at most about 11.7 GiB: every level of the 2-D model problem
+# at 2^19 points stays one launch, and the 3-D Matérn problem's L7 and L8
+# (15.7M and 27.4M block rows) go in two and three.
+ACA_PANEL_COLS = 64
 
 # ---------------------------------------------------------------------------
 # The fused structural program (Algs. 6 + 7 + 1/4 in one launch)
@@ -295,7 +305,10 @@ def compute_factors_device(tree: ClusterTree, plan: HMatrixPlan,
                            _counters: dict | None = None) -> dict:
     """Device-side twin of ``hmatrix.compute_factors`` (paper §5.4.1).
 
-    One construction launch per level group: the cluster-point gather
+    One construction launch per level group, or per static batch of its
+    blocks where the group is too large for one (:data:`ACA_PANEL_COLS`);
+    the larger groups go first, before the store has grown, and a batched
+    group's factors are joined in block order.  The cluster-point gather
     happens device-side from the tree-ordered point array, so the host
     never touches coordinates.  The default (``use_pallas=False``) runs
     the gather and then the ``batched_aca`` program inlined under the
@@ -308,30 +321,44 @@ def compute_factors_device(tree: ClusterTree, plan: HMatrixPlan,
     kfn = get_kernel(kernel) if isinstance(kernel, str) else kernel
     chaos_spec, retry, rng = _resolve_containment(chaos)
     counters = _counters if _counters is not None else _fresh_counters()
+    word = tree.points.dtype.itemsize
+
+    def rows_of(level):
+        return plan.aca_levels[level].shape[0] * (tree.n_pad >> level)
 
     factors = {}
-    for level, level_blocks in plan.aca_levels.items():
-        rows = jnp.asarray(level_blocks[:, 0])
-        cols = jnp.asarray(level_blocks[:, 1])
-        if use_pallas and kernel_name in KERNELS:
-            fn = partial(_aca_level_pallas, tree.points, rows, cols,
-                         level=level, kernel_name=kernel_name, k=k)
-        else:
-            def fn(level=level, rows=rows, cols=cols):
-                rp, cp = _level_points(tree.points, rows, cols, level=level)
-                return _level_aca(rp, cp, level=level, kernel=kfn, k=k)
-
+    for level in sorted(plan.aca_levels, key=rows_of, reverse=True):
+        level_blocks = plan.aca_levels[level]
+        m = tree.n_pad >> level
+        parts = []
         with jax.profiler.TraceAnnotation(f"{SPAN}.dispatch.L{level}"):
-            factors[level] = _contained_stage(f"build:factors:{level}", fn,
+            for part in launch_batches(level_blocks.shape[0],
+                                       m * ACA_PANEL_COLS * word):
+                rows = jnp.asarray(level_blocks[part, 0])
+                cols = jnp.asarray(level_blocks[part, 1])
+                if use_pallas and kernel_name in KERNELS:
+                    fn = partial(_aca_level_pallas, tree.points, rows, cols,
+                                 level=level, kernel_name=kernel_name, k=k)
+                else:
+                    def fn(level=level, rows=rows, cols=cols):
+                        rp, cp = _level_points(tree.points, rows, cols,
+                                               level=level)
+                        return _level_aca(rp, cp, level=level, kernel=kfn,
+                                          k=k)
+                parts.append(_contained_stage(f"build:factors:{level}", fn,
                                               chaos_spec, retry, rng,
-                                              counters)
-    return factors
+                                              counters))
+            factors[level] = (parts[0] if len(parts) == 1 else
+                              tuple(jnp.concatenate(f) for f in zip(*parts)))
+        counters["launches"] = counters.get("launches", 0) + len(parts)
+    return {level: factors[level] for level in plan.aca_levels}
 
 
-# One level group's launch.  Each is jitted with the level static, so its
-# device operations carry ``hmatrix.build.aca.L{level}/...`` (a scope
-# opened around an eager call would not reach a jitted callee).  The
-# shapes differ per level, so this is still one program per level.
+# One level group's launch (or one batch of it).  Each is jitted with the
+# level static, so its device operations carry
+# ``hmatrix.build.aca.L{level}/...`` (a scope opened around an eager call
+# would not reach a jitted callee).  The shapes differ per level, so this
+# is still one program per level (two where a batch is shorter).
 
 
 @partial(jax.jit, static_argnames=("level",))
@@ -398,9 +425,10 @@ class BuildReport:
     plan_s: float                   # fused structural program + fetch
     factors_s: float                # batched ACA level-group launches
     total_s: float
-    launches: int                   # device launches issued (1 + levels)
+    launches: int                   # device launches issued (1 + batches)
     num_aca_blocks: int
     num_dense_blocks: int
+    near_field_entries: int         # kernel entries each product regenerates
     retries: int = 0
     fallback_launches: int = 0
     faults_injected: dict = field(default_factory=dict)
@@ -466,7 +494,7 @@ def build_hmatrix_device_report(
 
     annotate = jax.profiler.TraceAnnotation
     t0 = time.perf_counter()
-    with annotate(f"{SPAN}.plan"):
+    with annotate(f"{SPAN}.plan", dim=d, kernel=kernel_name) as plan_span:
         spts, perm, iperm, bb_min, bb_max, meta = _contained_stage(
             "build:plan",
             lambda: _plan_program(coords, n_pad=n_pad, n_levels=n_levels,
@@ -475,6 +503,9 @@ def build_hmatrix_device_report(
         with annotate(f"{SPAN}.fetch"):
             plan = _assemble_plan(jax.device_get(meta), c_leaf, n_pad,
                                   n_levels, float(eta))
+        near_field_entries = plan.num_dense_blocks * c_leaf * c_leaf
+        plan_span.set_metadata(near_field_blocks=plan.num_dense_blocks,
+                               near_field_entries=near_field_entries)
         tree = ClusterTree(points=spts, perm=perm, iperm=iperm, n=n,
                            n_pad=n_pad, c_leaf=c_leaf, n_levels=n_levels,
                            bb_min=bb_min, bb_max=bb_max)
@@ -504,9 +535,10 @@ def build_hmatrix_device_report(
         n=n, n_pad=n_pad, n_levels=n_levels,
         plan_s=t1 - t0, factors_s=t2 - t1,
         total_s=(t2 - t0) + recompress_s,
-        launches=1 + (len(plan.aca_levels) if precompute else 0),
+        launches=1 + counters.get("launches", 0),
         num_aca_blocks=plan.num_aca_blocks,
         num_dense_blocks=plan.num_dense_blocks,
+        near_field_entries=near_field_entries,
         retries=counters["retries"],
         fallback_launches=counters["fallback_launches"],
         faults_injected=counters["faults_injected"],

@@ -15,6 +15,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from ..kernels._phi import phi_from_sqdist
+
 # ---------------------------------------------------------------------------
 # Halton sequences (quasi Monte-Carlo), as used for the paper's point sets.
 # ---------------------------------------------------------------------------
@@ -74,49 +76,16 @@ def gaussian_kernel(y: jnp.ndarray, yp: jnp.ndarray) -> jnp.ndarray:
     return jnp.exp(-_sqdist(y, yp))
 
 
-def _bessel_k1(x: jnp.ndarray) -> jnp.ndarray:
-    """Modified Bessel function K_1 via Abramowitz & Stegun 9.8.7 / 9.8.8.
-
-    Accurate to ~1e-7 relative, which is plenty for the Matérn convergence
-    study (the paper reports relative errors down to ~1e-8 in double).
-    """
-    x = jnp.asarray(x)
-    small = x <= 2.0
-    xs = jnp.where(small, x, 2.0)  # keep args in-range to avoid NaNs
-    xl = jnp.where(small, 2.0, x)
-
-    # --- x <= 2:  K1(x) = ln(x/2) I1(x) + (1/x) * poly((x/2)^2)
-    t = (xs / 3.75) ** 2
-    i1 = xs * (0.5 + t * (0.87890594 + t * (0.51498869 + t * (0.15084934
-         + t * (0.02658733 + t * (0.00301532 + t * 0.00032411))))))
-    u = (xs / 2.0) ** 2
-    p = 1.0 + u * (0.15443144 + u * (-0.67278579 + u * (-0.18156897
-        + u * (-0.01919402 + u * (-0.00110404 + u * (-0.00004686))))))
-    k1_small = jnp.log(xs / 2.0) * i1 + p / xs
-
-    # --- x > 2:  K1(x) = exp(-x)/sqrt(x) * poly(2/x)
-    w = 2.0 / xl
-    q = 1.25331414 + w * (0.23498619 + w * (-0.03655620 + w * (0.01504268
-        + w * (-0.00780353 + w * (0.00325614 + w * (-0.00068245))))))
-    k1_large = jnp.exp(-xl) / jnp.sqrt(xl) * q
-
-    return jnp.where(small, k1_small, k1_large)
-
-
 def matern_kernel(y: jnp.ndarray, yp: jnp.ndarray, d: int | None = None) -> jnp.ndarray:
     """Matérn kernel with ``beta - d/2 = 1`` (paper §6.2).
 
     phi_M(y,y') = K_1(r) r / (2^(beta-1) Gamma(beta)),  beta = d/2 + 1.
-    ``r * K_1(r) -> 1`` as ``r -> 0`` so the diagonal is finite.
+    ``r * K_1(r) -> 1`` as ``r -> 0`` so the diagonal is finite.  The
+    formula and its Bessel K_1 are ``kernels/_phi.phi_from_sqdist``'s, which
+    the Pallas kernels evaluate too.
     """
-    if d is None:
-        d = y.shape[-1]
-    beta = d / 2.0 + 1.0
-    norm = (2.0 ** (beta - 1.0)) * math.gamma(beta)
-    r = jnp.sqrt(_sqdist(y, yp))
-    tiny = 1e-30
-    val = jnp.where(r > 1e-8, r * _bessel_k1(jnp.maximum(r, tiny)), 1.0)
-    return val / norm
+    return phi_from_sqdist(_sqdist(y, yp), "matern",
+                           y.shape[-1] if d is None else d)
 
 
 KERNELS: dict[str, Callable] = {

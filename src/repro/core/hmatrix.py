@@ -46,6 +46,25 @@ from .geometry import get_kernel
 
 APPLY_SCOPE = "hmatrix.apply"
 
+# Most bytes of the (B, m, R) float32 operand panel that one launch gathers.
+# A level group's blocks, and the near field's, go in static batches under
+# it (the batching of the paper's §6.5), which bounds the gathered panel and
+# the product the contraction writes.  2.5 GiB keeps every launch of the
+# 2-D model problem at 2^19 points and R <= 64 whole (the largest, L8, is
+# (4610, 2048, 64): 2.25 GiB) and splits the 3-D Matérn problem's near
+# field, (18106, 2048, 64), in four: whole, it and its product do not fit
+# beside the 6.1 GiB store on a 16 GiB v5e.
+LAUNCH_BYTES = 5 << 29
+
+
+def launch_batches(n_blocks: int, block_bytes: int) -> list[slice]:
+    """Static slices of a list of ``n_blocks`` blocks of ``block_bytes``
+    each: as few batches as keep each under :data:`LAUNCH_BYTES`, as even
+    as they go (one slice of the whole list where it fits)."""
+    count = max(1, -(-n_blocks * block_bytes // LAUNCH_BYTES))
+    size = max(1, -(-n_blocks // count))
+    return [slice(i, i + size) for i in range(0, n_blocks, size)]
+
 
 @dataclass(frozen=True)
 class HMatrix:
@@ -182,9 +201,20 @@ def _aca_level_apply(tree, level, blocks, U, V, x_pad, z_pad, use_pallas):
 
 def _dense_apply_points(points, plan, kernel, x_pad, z_pad, use_pallas,
                         dense=None):
-    blocks = plan.dense_blocks
-    if blocks.shape[0] == 0:
-        return z_pad
+    c = plan.c_leaf
+    r = x_pad.shape[1]
+    for part in launch_batches(plan.dense_blocks.shape[0],
+                               c * r * x_pad.dtype.itemsize):
+        z_pad = _dense_batch_apply(points, plan, kernel, x_pad, z_pad,
+                                   use_pallas, part, dense)
+    return z_pad
+
+
+def _dense_batch_apply(points, plan, kernel, x_pad, z_pad, use_pallas, part,
+                       dense):
+    """One batch (``part``, a slice of ``plan.dense_blocks``) of the near
+    field: regenerate (or read) its blocks and add their products."""
+    blocks = plan.dense_blocks[part]
     c = plan.c_leaf
     r = x_pad.shape[1]
     n_leaf = plan.n_pad // c
@@ -198,7 +228,7 @@ def _dense_apply_points(points, plan, kernel, x_pad, z_pad, use_pallas,
         # stored dense leaves (FactorStore.dense): a straight batched MXU
         # contraction — no kernel regeneration, so no Pallas branch needed
         with jax.named_scope("contract"):
-            y = jnp.einsum("bij,bjr->bir", dense, x_blk)
+            y = jnp.einsum("bij,bjr->bir", dense[part], x_blk)
     elif use_pallas:
         from repro.kernels.batched_dense_matvec.ops import batched_kernel_matmat
         # one kernel regenerates each block and contracts it
@@ -258,16 +288,29 @@ def apply_in_tree_order(tree: ClusterTree, plan: HMatrixPlan, kernel: Callable,
     """
     with jax.named_scope(APPLY_SCOPE):
         z_pad = jnp.zeros_like(x_pad)
-        for level, blocks in plan.aca_levels.items():
+        row_bytes = x_pad.shape[1] * x_pad.dtype.itemsize
+        for level, level_blocks in plan.aca_levels.items():
+            m = tree.n_pad >> level
             with jax.named_scope(f"lowrank.L{level}"):
-                if factors is not None:
-                    U, V = factors[level]
-                else:
-                    with jax.named_scope("aca"):
-                        U, V = _regenerate_factors(tree, level, blocks, kernel,
-                                                   k, use_pallas, points)
-                z_pad = _aca_level_apply(tree, level, blocks, U, V, x_pad,
-                                         z_pad, use_pallas)
+                parts = launch_batches(level_blocks.shape[0], m * row_bytes)
+                for part in parts:
+                    blocks = level_blocks[part]
+                    if factors is not None:
+                        U, V = (f[part] for f in factors[level])
+                        if len(parts) > 1:
+                            # a batch's factors as arrays of their own: a
+                            # slice fused into the contraction made the
+                            # TPU's emitter walk the whole level (3.9 s for
+                            # one batch of the 3-D Matérn problem's L7, 51
+                            # ms for both batches as arrays of their own)
+                            U, V = jax.lax.optimization_barrier((U, V))
+                    else:
+                        with jax.named_scope("aca"):
+                            U, V = _regenerate_factors(tree, level, blocks,
+                                                       kernel, k, use_pallas,
+                                                       points)
+                    z_pad = _aca_level_apply(tree, level, blocks, U, V,
+                                             x_pad, z_pad, use_pallas)
         with jax.named_scope("dense"):
             return _dense_apply_points(points, plan, kernel, x_pad, z_pad,
                                        use_pallas,

@@ -55,7 +55,7 @@ def _phi_to(pts_t, p, kernel_name: str, point_dim: int):
     """phi between one point ``p`` (d, 1) and ``pts_t`` (d, n) -> (1, n)."""
     diff = pts_t - p
     d2 = jnp.sum(diff * diff, axis=0, keepdims=True)
-    return phi_from_sqdist(d2, kernel_name, point_dim)
+    return phi_from_sqdist(d2, kernel_name, point_dim, accuracy=None)
 
 
 def _kernel(rows_t_ref, cols_t_ref, ut_ref, vt_ref, *, k: int,

@@ -42,7 +42,8 @@ def _kernel_mm(rows_t_ref, cols_t_ref, x_ref, y_ref, *, kernel_name: str,
     cols_t = cols_t_ref[0]            # (d, C)
     x = x_ref[0]                      # (C, R)
     d2 = pairwise_sqdist_t(rows_t, cols_t)            # (C, C)  VPU
-    a = phi_from_sqdist(d2, kernel_name, point_dim)   # (C, C)  VPU
+    a = phi_from_sqdist(d2, kernel_name, point_dim,   # (C, C)  VPU
+                        accuracy=None)
     y_ref[0] = jnp.dot(a, x, preferred_element_type=jnp.float32)  # MXU
 
 

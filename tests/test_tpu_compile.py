@@ -95,3 +95,15 @@ def test_block_cholesky_solve_compiles(one_chip, r):
 def test_morton_compiles(one_chip):
     _compile(partial(morton_encode_t, interpret=False), "morton_encode",
              one_chip, (D, 1 << 18))
+
+
+def test_matern_kernels_compile(one_chip):
+    """The 3-D Matérn inside kernel bodies: its K_1 asks for the default log
+    and exp there, since the kernel compiler does not implement JAX's
+    accuracy request."""
+    _compile(partial(batched_kernel_matmat_t, kernel_name="matern",
+                     interpret=False), "batched_kernel_matmat", one_chip,
+             (B, 3, C_LEAF), (B, 3, C_LEAF), (B, C_LEAF, 64))
+    _compile(partial(batched_aca_t, kernel_name="matern", k=K,
+                     interpret=False), "batched_aca", one_chip,
+             (B, 3, C_LEAF), (B, 3, C_LEAF))

@@ -180,11 +180,31 @@ def test_build_writes_host_spans(points, tmp_path):
         hm, _ = build_hmatrix_device_report(points, k=K, c_leaf=C_LEAF,
                                             precompute=True)
     names = [n for n, _ in _host_events(str(tmp_path), "hmatrix.build.")]
-    dispatch = [f"hmatrix.build.dispatch.L{level}"
-                for level in hm.plan.aca_levels]
+    # the larger level groups (blocks x rows) are launched first
+    plan = hm.plan
+    dispatch = [f"hmatrix.build.dispatch.L{level}" for level in sorted(
+        plan.aca_levels, key=lambda lv: (plan.aca_levels[lv].shape[0]
+                                         * (plan.n_pad >> lv)),
+        reverse=True)]
     assert names == (["hmatrix.build.plan", "hmatrix.build.fetch",
                       "hmatrix.build.factors"] + dispatch
                      + ["hmatrix.build.wait", "hmatrix.build.store"])
+
+
+@pytest.mark.parametrize("dim,kernel", [(2, "gaussian"), (3, "matern")])
+def test_build_plan_span_carries_the_near_field(tmp_path, dim, kernel):
+    pts = jnp.asarray(halton(N, dim))
+    build_hmatrix_device_report(pts, kernel=kernel, k=K, c_leaf=C_LEAF)
+    with jax.profiler.trace(str(tmp_path)):
+        _, report = build_hmatrix_device_report(pts, kernel=kernel, k=K,
+                                                c_leaf=C_LEAF)
+    plan = [a for n, a in _host_events(str(tmp_path), "hmatrix.build.")
+            if n == "hmatrix.build.plan"]
+    assert report.num_dense_blocks > 0
+    assert report.near_field_entries == report.num_dense_blocks * C_LEAF ** 2
+    assert plan == [{"dim": dim, "kernel": kernel,
+                     "near_field_blocks": report.num_dense_blocks,
+                     "near_field_entries": report.near_field_entries}]
 
 
 def test_serving_writes_launch_and_fetch_spans(points, tmp_path):
