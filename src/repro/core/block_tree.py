@@ -35,6 +35,10 @@ class HMatrixPlan:
                  of admissible blocks at that level (approximated at rank k).
     dense_blocks: (n_dense, 2) int32 array at leaf level (direct evaluation).
     c_leaf, n_pad, n_levels: geometry of the partition.
+    dense_range: (n_dense, 2) float64 bounds [r_lo, r_hi] on the distance
+                 between any point of a dense block's row leaf and any of
+                 its column leaf (:func:`near_field_ranges`), or None where
+                 they are not known (every branch of the kernel is taken).
     """
 
     aca_levels: dict
@@ -43,6 +47,7 @@ class HMatrixPlan:
     n_pad: int
     n_levels: int
     eta: float
+    dense_range: np.ndarray | None = None
 
     @property
     def num_aca_blocks(self) -> int:
@@ -75,6 +80,20 @@ def _admissible_np(a_min, a_max, b_min, b_max, eta):
     # eta would promote the comparison to f64 under pre-NEP50 NumPy and
     # could flip borderline blocks vs the device traversal
     return np.minimum(d_a, d_b) <= np.float32(eta) * dist
+
+
+def near_field_ranges(leaf_min, leaf_max, blocks) -> np.ndarray:
+    """(n_blocks, 2) float64 ``[r_lo, r_hi]`` for leaf blocks ``(i, j)``:
+    the gap between the two leaf boxes (``dist`` of the admissibility test)
+    and the length of the box spanned by the farthest corners,
+    sqrt(sum_d max(max_i - min_j, max_j - min_i)^2)."""
+    lo = np.asarray(leaf_min, np.float64)
+    hi = np.asarray(leaf_max, np.float64)
+    r, c = np.asarray(blocks[:, 0]), np.asarray(blocks[:, 1])
+    gap = (np.maximum(0.0, lo[r] - hi[c]) ** 2
+           + np.maximum(0.0, lo[c] - hi[r]) ** 2).sum(-1)
+    span = (np.maximum(hi[r] - lo[c], hi[c] - lo[r]) ** 2).sum(-1)
+    return np.stack([np.sqrt(gap), np.sqrt(span)], axis=1)
 
 
 def build_block_tree(tree: ClusterTree, eta: float = 1.5,
@@ -138,6 +157,9 @@ def build_block_tree(tree: ClusterTree, eta: float = 1.5,
 
     if dense_blocks is None:
         dense_blocks = np.zeros((0, 2), np.int32)
+    leaf = tree.n_levels
     return HMatrixPlan(aca_levels=aca_levels, dense_blocks=dense_blocks,
                        c_leaf=tree.c_leaf, n_pad=tree.n_pad,
-                       n_levels=tree.n_levels, eta=eta)
+                       n_levels=tree.n_levels, eta=eta,
+                       dense_range=near_field_ranges(
+                           bb_min[leaf], bb_max[leaf], dense_blocks))

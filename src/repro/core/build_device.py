@@ -63,11 +63,11 @@ import numpy as np
 
 from .aca import batched_aca
 from .admissibility import admissible
-from .block_tree import HMatrixPlan
+from .block_tree import HMatrixPlan, near_field_ranges
 from .clustering import ClusterTree, next_pow2
 from .factor_store import FactorStore, recompress_store
 from .geometry import get_kernel, KERNELS
-from .hmatrix import HMatrix, launch_batches
+from .hmatrix import HMatrix, launch_batches, near_field_pruned_blocks
 from .morton import morton_encode
 
 # Names of the build's device scopes (op metadata) and host spans
@@ -218,8 +218,10 @@ def _block_tree(mins, maxs, n_levels: int, eta: float):
 
 
 def _assemble_plan(meta: np.ndarray, c_leaf: int, n_pad: int,
-                   n_levels: int, eta: float) -> HMatrixPlan:
-    """Slice the fetched metadata vector into the host-layout plan."""
+                   n_levels: int, eta: float, leaf_min: np.ndarray,
+                   leaf_max: np.ndarray) -> HMatrixPlan:
+    """Slice the fetched metadata vector into the host-layout plan, with
+    the dense blocks' distance bounds from the fetched leaf boxes."""
     counts = meta[: n_levels + 2]
     off = n_levels + 2
     aca_levels: dict[int, np.ndarray] = {}
@@ -239,7 +241,8 @@ def _assemble_plan(meta: np.ndarray, c_leaf: int, n_pad: int,
     dense = np.stack([r[:n_dense], c[:n_dense]], axis=1).astype(np.int32)
     return HMatrixPlan(aca_levels=aca_levels, dense_blocks=dense,
                        c_leaf=c_leaf, n_pad=n_pad, n_levels=n_levels,
-                       eta=eta)
+                       eta=eta, dense_range=near_field_ranges(
+                           leaf_min, leaf_max, dense))
 
 
 # ---------------------------------------------------------------------------
@@ -429,6 +432,8 @@ class BuildReport:
     num_aca_blocks: int
     num_dense_blocks: int
     near_field_entries: int         # kernel entries each product regenerates
+    near_field_pruned_blocks: int   # dense blocks regenerated without some
+                                    # of the kernel's branches
     retries: int = 0
     fallback_launches: int = 0
     faults_injected: dict = field(default_factory=dict)
@@ -501,11 +506,16 @@ def build_hmatrix_device_report(
                                   eta=float(eta)),
             chaos_spec, retry, rng, counters)
         with annotate(f"{SPAN}.fetch"):
-            plan = _assemble_plan(jax.device_get(meta), c_leaf, n_pad,
-                                  n_levels, float(eta))
+            # the leaf boxes come in the same fetch as the metadata
+            meta_h, leaf_min, leaf_max = jax.device_get(
+                (meta, bb_min[n_levels], bb_max[n_levels]))
+            plan = _assemble_plan(meta_h, c_leaf, n_pad, n_levels,
+                                  float(eta), leaf_min, leaf_max)
         near_field_entries = plan.num_dense_blocks * c_leaf * c_leaf
+        near_field_pruned = near_field_pruned_blocks(plan, kfn)
         plan_span.set_metadata(near_field_blocks=plan.num_dense_blocks,
-                               near_field_entries=near_field_entries)
+                               near_field_entries=near_field_entries,
+                               near_field_pruned_blocks=near_field_pruned)
         tree = ClusterTree(points=spts, perm=perm, iperm=iperm, n=n,
                            n_pad=n_pad, c_leaf=c_leaf, n_levels=n_levels,
                            bb_min=bb_min, bb_max=bb_max)
@@ -539,6 +549,7 @@ def build_hmatrix_device_report(
         num_aca_blocks=plan.num_aca_blocks,
         num_dense_blocks=plan.num_dense_blocks,
         near_field_entries=near_field_entries,
+        near_field_pruned_blocks=near_field_pruned,
         retries=counters["retries"],
         fallback_launches=counters["fallback_launches"],
         faults_injected=counters["faults_injected"],

@@ -15,7 +15,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from ..kernels._phi import phi_from_sqdist
+from ..kernels._phi import BRANCH_POINTS, phi_from_sqdist
 
 # ---------------------------------------------------------------------------
 # Halton sequences (quasi Monte-Carlo), as used for the paper's point sets.
@@ -76,17 +76,25 @@ def gaussian_kernel(y: jnp.ndarray, yp: jnp.ndarray) -> jnp.ndarray:
     return jnp.exp(-_sqdist(y, yp))
 
 
-def matern_kernel(y: jnp.ndarray, yp: jnp.ndarray, d: int | None = None) -> jnp.ndarray:
+def matern_kernel(y: jnp.ndarray, yp: jnp.ndarray, d: int | None = None,
+                  r_range: tuple[float, float] | None = None) -> jnp.ndarray:
     """Matérn kernel with ``beta - d/2 = 1`` (paper §6.2).
 
     phi_M(y,y') = K_1(r) r / (2^(beta-1) Gamma(beta)),  beta = d/2 + 1.
     ``r * K_1(r) -> 1`` as ``r -> 0`` so the diagonal is finite.  The
     formula and its Bessel K_1 are ``kernels/_phi.phi_from_sqdist``'s, which
-    the Pallas kernels evaluate too.
+    the Pallas kernels evaluate too.  ``r_range=(lo, hi)``, static, bounds
+    every distance between ``y`` and ``yp``: K_1 then evaluates only the
+    branches it reaches (``matern_kernel.branch_points``).
     """
     return phi_from_sqdist(_sqdist(y, yp), "matern",
-                           y.shape[-1] if d is None else d)
+                           y.shape[-1] if d is None else d, r_range=r_range)
 
+
+# The distances at which a kernel switches expression: a kernel with any
+# takes ``r_range=`` (see ``kernels/_phi.py``).
+gaussian_kernel.branch_points = BRANCH_POINTS["gaussian"]
+matern_kernel.branch_points = BRANCH_POINTS["matern"]
 
 KERNELS: dict[str, Callable] = {
     "gaussian": gaussian_kernel,

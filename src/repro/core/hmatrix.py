@@ -43,6 +43,7 @@ from .block_tree import HMatrixPlan, build_block_tree
 from .clustering import ClusterTree, build_cluster_tree, permute_from_tree, permute_to_tree
 from .factor_store import FactorStore, recompress_store
 from .geometry import get_kernel
+from ..kernels._phi import branch_side
 
 APPLY_SCOPE = "hmatrix.apply"
 
@@ -199,22 +200,64 @@ def _aca_level_apply(tree, level, blocks, U, V, x_pad, z_pad, use_pallas):
         return z_pad + zl.reshape(-1, r)
 
 
+def _branch_sides(plan: HMatrixPlan, kernel: Callable) -> np.ndarray | None:
+    """(n_dense, P): the side of each of the kernel's P branch points that
+    each dense block's distance range lies on (``kernels/_phi.branch_side``),
+    or None where the kernel has no branch point or the plan no ranges."""
+    branch_points = getattr(kernel, "branch_points", ())
+    if not branch_points or plan.dense_range is None:
+        return None
+    lo, hi = plan.dense_range[:, 0], plan.dense_range[:, 1]
+    return np.stack([branch_side(lo, hi, p) for p in branch_points], axis=1)
+
+
+def near_field_groups(plan: HMatrixPlan, kernel: Callable) -> list:
+    """The near field as ``(selection, r_range)`` groups of blocks that reach
+    the same branches of the kernel: ``plan.dense_blocks[selection]`` in the
+    plan's order, regenerated with the static ``r_range`` (the hull of the
+    group's distance bounds; None for a kernel without branch points).
+    Where every block falls in one group, the selection is the whole list."""
+    sides = _branch_sides(plan, kernel)
+    if sides is None or sides.shape[0] == 0:
+        return [(slice(None), None)]
+    keys, inverse = np.unique(sides, axis=0, return_inverse=True)
+    lo, hi = plan.dense_range[:, 0], plan.dense_range[:, 1]
+    groups = []
+    for g in range(len(keys)):
+        sel = np.flatnonzero(inverse.reshape(-1) == g)
+        groups.append((slice(None) if len(keys) == 1 else sel,
+                       (float(lo[sel].min()), float(hi[sel].max()))))
+    return groups
+
+
+def near_field_pruned_blocks(plan: HMatrixPlan, kernel: Callable) -> int:
+    """Dense blocks whose regeneration skips a branch of the kernel."""
+    sides = _branch_sides(plan, kernel)
+    return 0 if sides is None else int((sides != 0).any(axis=1).sum())
+
+
 def _dense_apply_points(points, plan, kernel, x_pad, z_pad, use_pallas,
                         dense=None):
     c = plan.c_leaf
     r = x_pad.shape[1]
-    for part in launch_batches(plan.dense_blocks.shape[0],
-                               c * r * x_pad.dtype.itemsize):
-        z_pad = _dense_batch_apply(points, plan, kernel, x_pad, z_pad,
-                                   use_pallas, part, dense)
+    # stored leaves and the Pallas kernel take the whole list as one group
+    groups = ([(slice(None), None)] if use_pallas or dense is not None
+              else near_field_groups(plan, kernel))
+    for sel, r_range in groups:
+        blocks = plan.dense_blocks[sel]
+        for part in launch_batches(blocks.shape[0],
+                                   c * r * x_pad.dtype.itemsize):
+            z_pad = _dense_batch_apply(
+                points, plan, kernel, x_pad, z_pad, use_pallas, blocks[part],
+                None if dense is None else dense[part], r_range)
     return z_pad
 
 
-def _dense_batch_apply(points, plan, kernel, x_pad, z_pad, use_pallas, part,
-                       dense):
-    """One batch (``part``, a slice of ``plan.dense_blocks``) of the near
-    field: regenerate (or read) its blocks and add their products."""
-    blocks = plan.dense_blocks[part]
+def _dense_batch_apply(points, plan, kernel, x_pad, z_pad, use_pallas, blocks,
+                       dense, r_range):
+    """One batch (``blocks``, rows of ``plan.dense_blocks``) of the near
+    field: regenerate its blocks, with the kernel's static ``r_range``
+    where one is given, or read them (``dense``), and add their products."""
     c = plan.c_leaf
     r = x_pad.shape[1]
     n_leaf = plan.n_pad // c
@@ -228,7 +271,7 @@ def _dense_batch_apply(points, plan, kernel, x_pad, z_pad, use_pallas, part,
         # stored dense leaves (FactorStore.dense): a straight batched MXU
         # contraction — no kernel regeneration, so no Pallas branch needed
         with jax.named_scope("contract"):
-            y = jnp.einsum("bij,bjr->bir", dense[part], x_blk)
+            y = jnp.einsum("bij,bjr->bir", dense, x_blk)
     elif use_pallas:
         from repro.kernels.batched_dense_matvec.ops import batched_kernel_matmat
         # one kernel regenerates each block and contracts it
@@ -237,7 +280,8 @@ def _dense_batch_apply(points, plan, kernel, x_pad, z_pad, use_pallas, part,
                                       tree_kernel_name(kernel))
     else:
         with jax.named_scope("kernel"):
-            a = kernel(pts_r, pts_c)                           # (B, c, c)
+            a = (kernel(pts_r, pts_c) if r_range is None
+                 else kernel(pts_r, pts_c, r_range=r_range))   # (B, c, c)
         with jax.named_scope("contract"):
             y = jnp.einsum("bij,bjr->bir", a, x_blk)
     with jax.named_scope("scatter"):

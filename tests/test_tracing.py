@@ -202,9 +202,14 @@ def test_build_plan_span_carries_the_near_field(tmp_path, dim, kernel):
             if n == "hmatrix.build.plan"]
     assert report.num_dense_blocks > 0
     assert report.near_field_entries == report.num_dense_blocks * C_LEAF ** 2
+    # the Matérn's K_1 branches at r = 2, which no pair in [0,1]^d reaches:
+    # every near-field block skips the far branch; the Gaussian has none
+    pruned = report.num_dense_blocks if kernel == "matern" else 0
+    assert report.near_field_pruned_blocks == pruned
     assert plan == [{"dim": dim, "kernel": kernel,
                      "near_field_blocks": report.num_dense_blocks,
-                     "near_field_entries": report.near_field_entries}]
+                     "near_field_entries": report.near_field_entries,
+                     "near_field_pruned_blocks": pruned}]
 
 
 def test_serving_writes_launch_and_fetch_spans(points, tmp_path):
